@@ -28,6 +28,8 @@ from typing import Any
 
 import numpy as np
 
+_EMB_CHUNK = 32768     # corpus rows per embedding-generation step
+
 
 @dataclasses.dataclass(frozen=True)
 class WorldConfig:
@@ -79,7 +81,7 @@ class SyntheticWorld:
         n_docs = cfg.n_docs
         self.doc_entity = np.repeat(np.arange(cfg.n_entities), cfg.docs_per_entity)
         self.doc_attr_mask = np.zeros((n_docs, cfg.attrs_per_entity), bool)
-        attr_mix = np.zeros((n_docs, d), np.float32)
+        doc_sel = np.zeros((n_docs, cfg.attrs_per_doc), np.int64)
         for i in range(cfg.docs_per_entity):
             sel = rng.random((cfg.n_entities, cfg.attrs_per_entity)).argsort(axis=1)
             sel = sel[:, :cfg.attrs_per_doc]                       # [E, apd]
@@ -87,13 +89,22 @@ class SyntheticWorld:
                 i::cfg.docs_per_entity]
             for j in range(cfg.attrs_per_doc):
                 self.doc_attr_mask[rows, sel[:, j]] = True
-            attr_mix[rows] = self.attr_basis[sel].sum(axis=1) \
-                / np.sqrt(cfg.attrs_per_doc)
+            doc_sel[rows] = sel
 
-        emb = (cfg.entity_weight * self.entity_vecs[self.doc_entity]
-               + cfg.attr_weight_doc * attr_mix
-               + cfg.noise_doc * unit(rng.normal(size=(n_docs, d))))
-        self.doc_emb = unit(emb).astype(np.float32)
+        # embeddings in row chunks: every step is row-wise and the noise
+        # stream is drawn in order, so the result is bit-identical to one
+        # whole-corpus pass while the float64 temporaries stay one chunk
+        # (a 1M x 768 corpus would otherwise need ~25 GB of them)
+        self.doc_emb = np.empty((n_docs, d), np.float32)
+        for lo in range(0, n_docs, _EMB_CHUNK):
+            rows = slice(lo, min(lo + _EMB_CHUNK, n_docs))
+            attr_mix = (self.attr_basis[doc_sel[rows]].sum(axis=1)
+                        / np.sqrt(cfg.attrs_per_doc)).astype(np.float32)
+            noise = rng.normal(size=(attr_mix.shape[0], d))
+            emb = (cfg.entity_weight * self.entity_vecs[self.doc_entity[rows]]
+                   + cfg.attr_weight_doc * attr_mix
+                   + cfg.noise_doc * unit(noise))
+            self.doc_emb[rows] = unit(emb)
 
         # entity -> attribute availability (a query can only ask attrs that
         # at least one doc of the entity covers)

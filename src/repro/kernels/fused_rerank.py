@@ -38,57 +38,73 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _fuse_scores(q, ids, vecs, *, kd: int, kl: int, rrf_k: float,
+def _fuse_scores(q, ids, ids_col, vecs, *, kd: int, kl: int, rrf_k: float,
                  diversify_sim: float | None):
-    """Fuse one query's pool: q [d], ids [P], vecs [P,d] -> ([P], [P]) f32.
+    """Fuse one query's pool: q [1,d], ids [1,P] (and the same ids as a
+    column, [P,1]), vecs [P,d] -> ([1,P], [1,P]) f32.
 
     Returns ``(mass, rscore)``: the fused RRF mass for selected docs
     (``-inf`` for dropped ones — invalid slots, duplicate occurrences,
     diversity rejects) and the dense rerank score used as the tie-break
-    key.  Shared by the kernel body and the XLA oracle.
+    key.  Every value is 2-D and every pick a masked reduction, so the
+    body lowers through Mosaic as written.  Shared by the kernel body and
+    the XLA oracle.
     """
     p = kd + kl
-    rank = jnp.concatenate([jnp.arange(kd), jnp.arange(kl)]).astype(jnp.float32)
-    pos = jnp.arange(p, dtype=jnp.int32)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, p), 1)
+    pos_col = jax.lax.broadcasted_iota(jnp.int32, (p, 1), 0)
+    rank_col = jnp.where(pos_col < kd, pos_col, pos_col - kd).astype(
+        jnp.float32)
     valid = ids >= 0
-    raw = jnp.where(valid, 1.0 / (rrf_k + rank), 0.0)
+    valid_col = ids_col >= 0
+    raw_col = jnp.where(valid_col, 1.0 / (rrf_k + rank_col), 0.0)   # [P, 1]
     # combine duplicate ids: all of an id's mass lands on its first slot
-    same = (ids[:, None] == ids[None, :]) & valid[:, None] & valid[None, :]
-    first = ~jnp.any(same & (pos[None, :] < pos[:, None]), axis=1)
-    mass = jnp.sum(jnp.where(same, raw[None, :], 0.0), axis=1)
-    mass = jnp.where(first & valid, mass, 0.0)
+    # (same[j, i]: slot j holds slot i's id; the matrix is symmetric)
+    same = (ids_col == ids) & valid_col & valid                     # [P, P]
+    dup_before = jnp.max(jnp.where(same & (pos_col < pos), 1.0, 0.0),
+                         axis=0, keepdims=True) > 0.0
+    mass = jnp.sum(jnp.where(same, raw_col, 0.0), axis=0, keepdims=True)
+    mass = jnp.where(valid & ~dup_before, mass, 0.0)                # [1, P]
 
-    rscore = vecs.astype(jnp.float32) @ q.astype(jnp.float32)
+    dot = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    rscore = dot(q, vecs)                                           # [1, P]
     if diversify_sim is None:
         selected = mass > 0.0
     else:
-        norm = jnp.sqrt(jnp.sum(vecs * vecs, axis=1))
-        vn = vecs / jnp.maximum(norm, 1e-12)[:, None]
-        sims = vn @ vn.T                                   # [P, P] cosine
+        norm = jnp.sqrt(jnp.sum(vecs * vecs, axis=1, keepdims=True))
+        vn = vecs / jnp.maximum(norm, 1e-12)
+        sims = dot(vn, vn)                                          # [P, P]
 
-        def body(i, carry):
+        def body(i, carry):                # selected: 1.0 / 0.0 per slot
             selected, rem = carry
-            c = jnp.argmax(rem)                            # next-best mass
-            eligible = rem[c] > 0.0
-            msim = jnp.max(jnp.where(selected, sims[c], -jnp.inf))
-            keep = eligible & (msim < diversify_sim)
-            selected = selected | ((pos == c) & keep)
-            rem = jnp.where(pos == c, 0.0, rem)
+            top = jnp.max(rem, axis=1, keepdims=True)      # next-best mass
+            c = jnp.argmax(rem, axis=1).astype(jnp.int32)[:, None]
+            row = jnp.max(jnp.where(pos_col == c, sims, -jnp.inf), axis=0,
+                          keepdims=True)                   # sims[c, :]
+            msim = jnp.max(jnp.where(selected > 0.0, row, -jnp.inf),
+                           axis=1, keepdims=True)
+            keep = (top > 0.0) & (msim < diversify_sim)
+            at = pos == c
+            selected = jnp.where(at & keep, 1.0, selected)
+            rem = jnp.where(at, 0.0, rem)
             return selected, rem
 
         selected, _ = jax.lax.fori_loop(
-            0, p, body, (jnp.zeros((p,), bool), mass))
+            0, p, body, (jnp.zeros((1, p), jnp.float32), mass))
+        selected = selected > 0.0
     return jnp.where(selected, mass, -jnp.inf), rscore
 
 
-def _fused_kernel(q_ref, ids_ref, vecs_ref, mass_ref, rscore_ref, *,
-                  kd: int, kl: int, rrf_k: float,
+def _fused_kernel(q_ref, ids_ref, ids_col_ref, vecs_ref, mass_ref,
+                  rscore_ref, *, kd: int, kl: int, rrf_k: float,
                   diversify_sim: float | None):
-    mass, rscore = _fuse_scores(q_ref[0], ids_ref[0], vecs_ref[0], kd=kd,
-                                kl=kl, rrf_k=rrf_k,
+    mass, rscore = _fuse_scores(q_ref[0], ids_ref[0], ids_col_ref[0],
+                                vecs_ref[0], kd=kd, kl=kl, rrf_k=rrf_k,
                                 diversify_sim=diversify_sim)
-    mass_ref[...] = mass[None, :]
-    rscore_ref[...] = rscore[None, :]
+    mass_ref[0] = mass
+    rscore_ref[0] = rscore
 
 
 def _final_topk(sel_mass, rscore, pool_ids, k: int):
@@ -121,20 +137,23 @@ def fused_rerank(queries: jax.Array, pool_ids: jax.Array,
     b, p = pool_ids.shape
     d = queries.shape[1]
     kl = p - kd
+    pool_ids = pool_ids.astype(jnp.int32)
     mass, rscore = pl.pallas_call(
         functools.partial(_fused_kernel, kd=kd, kl=kl, rrf_k=rrf_k,
                           diversify_sim=diversify_sim),
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i: (i, 0)),        # this query
-            pl.BlockSpec((1, p), lambda i: (i, 0)),        # its fused pool
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),  # this query
+            pl.BlockSpec((1, 1, p), lambda i: (i, 0, 0)),  # its fused pool
+            pl.BlockSpec((1, p, 1), lambda i: (i, 0, 0)),  # ... as a column
             pl.BlockSpec((1, p, d), lambda i: (i, 0, 0)),
         ],
-        out_specs=[pl.BlockSpec((1, p), lambda i: (i, 0)),
-                   pl.BlockSpec((1, p), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, p), jnp.float32),
-                   jax.ShapeDtypeStruct((b, p), jnp.float32)],
+        out_specs=[pl.BlockSpec((1, 1, p), lambda i: (i, 0, 0)),
+                   pl.BlockSpec((1, 1, p), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, 1, p), jnp.float32),
+                   jax.ShapeDtypeStruct((b, 1, p), jnp.float32)],
         interpret=interpret,
-    )(queries.astype(jnp.float32), pool_ids.astype(jnp.int32),
-      pool_vecs.astype(jnp.float32))
+    )(queries.astype(jnp.float32)[:, None, :], pool_ids[:, None, :],
+      pool_ids[:, :, None], pool_vecs.astype(jnp.float32))
+    mass, rscore = mass[:, 0], rscore[:, 0]
     return _final_topk(mass, rscore, pool_ids, k)

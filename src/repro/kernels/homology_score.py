@@ -14,8 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _homology_kernel(draft_ref, cache_ref, valid_ref, *rest, k: int,
-                     grouped: bool, weighted: bool):
+def _homology_kernel(draft_ref, cache_ref, *rest, k: int, grouped: bool,
+                     weighted: bool):
     rest = list(rest)
     w_ref = rest.pop(0) if weighted else None
     if grouped:
@@ -23,25 +23,29 @@ def _homology_kernel(draft_ref, cache_ref, valid_ref, *rest, k: int,
     else:
         (out_ref,), row_group_ref, q_group_ref = rest, None, None
     draft = draft_ref[...]                                 # [B, k]
-    cache = cache_ref[...]                                 # [TILE_H, k]
-    valid = valid_ref[...]                                 # [TILE_H]
-    # [B, TILE_H, k_draft, k_cache] compare; any over cache slots; sum draft
-    eq = (draft[:, None, :, None] == cache[None, :, None, :])
-    eq &= (draft[:, None, :, None] >= 0)
-    hit = jnp.any(eq, axis=3).astype(jnp.float32)          # [B, TILE_H, k]
-    if weighted:
-        # fused-list validation: each draft slot carries its (normalized)
-        # RRF mass instead of 1/k — rank-domain, score-scale free
-        s = jnp.sum(hit * w_ref[...][:, None, :], axis=2)
-    else:
-        overlap = jnp.sum(hit, axis=2)
-        s = overlap / k
-    ok = valid[None, :]
+    cache = cache_ref[...]                                 # [k, TILE_H]
+    # draft slot j hits row i when any cached slot of row i holds its id:
+    # k*k compares of [B, 1] against [1, TILE_H], all 2-D for Mosaic
+    s = None
+    for j in range(k):
+        dj = draft[:, j:j + 1]                             # [B, 1]
+        hit = dj == cache[0:1, :]
+        for c in range(1, k):
+            hit |= dj == cache[c:c + 1, :]
+        hit = (hit & (dj >= 0)).astype(jnp.float32)        # [B, TILE_H]
+        if weighted:
+            # fused-list validation: each draft slot carries its
+            # (normalized) RRF mass instead of 1/k — rank-domain,
+            # score-scale free
+            hit = hit * w_ref[:, j:j + 1]
+        s = hit if s is None else s + hit
+    if not weighted:
+        s = s / k
     if grouped:
         # partitioned table: cached query row i only scores against drafts
         # of its own group (tenant) — cross-tenant rows read as 0 overlap
-        ok &= row_group_ref[...][None, :] == q_group_ref[...][:, None]
-    out_ref[...] = jnp.where(ok, s, 0.0)
+        s = jnp.where(row_group_ref[...] == q_group_ref[...], s, 0.0)
+    out_ref[...] = s
 
 
 @functools.partial(jax.jit, static_argnames=("tile_h", "interpret"))
@@ -63,7 +67,11 @@ def homology_score(draft_ids: jax.Array, cache_doc_ids: jax.Array,
     uniform overlap ratio (1/k per matched slot) to per-slot weighted mass
     (the fused-list RRF validation of ``HasConfig.fusion == "rrf"``;
     weights pre-normalized by :func:`~repro.core.homology.rrf_draft_weights`).
-    Absent, the program is byte-identical to the unweighted kernel.
+
+    Invalid rows get id ``-2`` in every slot before the call: drafts only
+    count ids ``>= 0``, so such a row scores exactly 0 with no validity
+    stream.  The table streams transposed (``[k, H]``), so every block is
+    2-D with a lane-aligned ``tile_h``.
     """
     b, k = draft_ids.shape
     h = cache_doc_ids.shape[0]
@@ -73,30 +81,27 @@ def homology_score(draft_ids: jax.Array, cache_doc_ids: jax.Array,
     weighted = draft_weights is not None
     n_tiles = pl.cdiv(h, tile_h)
     pad = n_tiles * tile_h - h
+    cache_t = jnp.where(cache_valid[:, None], cache_doc_ids, -2).T   # [k, H]
     if pad:
-        cache_doc_ids = jnp.concatenate(
-            [cache_doc_ids, jnp.full((pad, k), -2, jnp.int32)], axis=0)
-        cache_valid = jnp.concatenate(
-            [cache_valid, jnp.zeros((pad,), bool)], axis=0)
+        cache_t = jnp.pad(cache_t, ((0, 0), (0, pad)), constant_values=-2)
         if grouped:
-            row_group = jnp.concatenate(
-                [row_group, jnp.full((pad,), -1, jnp.int32)])
+            row_group = jnp.pad(row_group, (0, pad), constant_values=-1)
 
     in_specs = [
         pl.BlockSpec((b, k), lambda i: (0, 0)),
-        pl.BlockSpec((tile_h, k), lambda i: (i, 0)),
-        pl.BlockSpec((tile_h,), lambda i: (i,)),
+        pl.BlockSpec((k, tile_h), lambda i: (0, i)),
     ]
-    operands = [draft_ids, cache_doc_ids, cache_valid]
+    operands = [draft_ids, cache_t]
     if weighted:
         in_specs += [pl.BlockSpec((b, k), lambda i: (0, 0))]  # weights resident
         operands += [draft_weights.astype(jnp.float32)]
     if grouped:
         in_specs += [
-            pl.BlockSpec((tile_h,), lambda i: (i,)),       # row groups
-            pl.BlockSpec((b,), lambda i: (0,)),            # query groups
+            pl.BlockSpec((1, tile_h), lambda i: (0, i)),   # row groups
+            pl.BlockSpec((b, 1), lambda i: (0, 0)),        # query groups
         ]
-        operands += [row_group.astype(jnp.int32), q_group.astype(jnp.int32)]
+        operands += [row_group.astype(jnp.int32)[None, :],
+                     q_group.astype(jnp.int32)[:, None]]
 
     out = pl.pallas_call(
         functools.partial(_homology_kernel, k=k, grouped=grouped,

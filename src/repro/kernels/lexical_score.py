@@ -12,8 +12,9 @@ retrieval has no notion of "closest" doc when nothing matches, unlike the
 dense channel.
 
 TPU mapping (same shape as ``topk_search``):
-  * grid = postings tiles; each step streams a [TILE_N, L] block of doc
-    terms + weights into VMEM while the query terms stay resident.
+  * grid = postings tiles; each step streams an [L, TILE_N] block of doc
+    terms + weights (postings transposed, lane-dense) into VMEM while the
+    query terms stay resident.
   * the match is L·T vector-unit integer compares per tile (T = query terms,
     L = doc postings width — both single digits), no MXU work at all: the
     channel is bandwidth-bound on the postings stream, which is the point
@@ -37,20 +38,25 @@ from jax.experimental import pallas as pl
 def _tile_scores(q_terms, q_weights, doc_terms, doc_weights):
     """Hashed-term match mass for one postings tile.
 
-    q_terms/q_weights [B, T], doc_terms/doc_weights [C, L] -> [B, C] f32,
-    with non-positive mass (no term matched) masked to ``-inf``.  Shared by
-    the kernel body and the XLA oracle so the math is identical by
-    construction.
+    q_terms/q_weights [B, T], doc_terms/doc_weights [L, C] (postings
+    transposed: one row per postings slot) -> [B, C] f32, with
+    non-positive mass (no term matched) masked to ``-inf``.  Every
+    intermediate is a 2-D [B, C] tile, so no [B, C, L] block ever lands in
+    VMEM.  Shared by the kernel body and the XLA oracle so the math is
+    identical by construction.
     """
-    t_q = q_terms.shape[1]
-    dt = doc_terms[None, :, :]                             # [1, C, L]
-    dw = doc_weights[None, :, :].astype(jnp.float32)
-    s = jnp.zeros((q_terms.shape[0], doc_terms.shape[0]), jnp.float32)
-    for t in range(t_q):                                   # static: T is tiny
-        qt = q_terms[:, t][:, None, None]                  # [B, 1, 1]
-        hit = (dt == qt) & (dt >= 0) & (qt >= 0)
-        s = s + q_weights[:, t][:, None] * jnp.sum(
-            jnp.where(hit, dw, 0.0), axis=2)
+    s = None
+    for t in range(q_terms.shape[1]):                      # static: T is tiny
+        qt = q_terms[:, t:t + 1]                           # [B, 1]
+        mass = None
+        for l in range(doc_terms.shape[0]):                # static: L is tiny
+            dt = doc_terms[l:l + 1, :]                     # [1, C]
+            hit = (dt == qt) & (dt >= 0) & (qt >= 0)
+            m = jnp.where(hit, doc_weights[l:l + 1, :].astype(jnp.float32),
+                          0.0)
+            mass = m if mass is None else mass + m
+        term = q_weights[:, t:t + 1] * mass
+        s = term if s is None else s + term
     return jnp.where(s > 0.0, s, -jnp.inf)
 
 
@@ -91,17 +97,14 @@ def _final_sort(vals, idx):
 
 
 def _pad_postings(doc_terms, doc_weights, tile_n: int):
-    """Pad postings rows to a tile multiple with inert (-1 / 0) rows."""
+    """Pad postings rows to a tile multiple with inert (-1 / 0) rows and
+    transpose them to ``[L, N_pad]`` (the layout the kernel streams)."""
     n = doc_terms.shape[0]
     n_tiles = pl.cdiv(n, tile_n)
-    pad = n_tiles * tile_n - n
-    if pad:
-        doc_terms = jnp.concatenate(
-            [doc_terms, jnp.full((pad, doc_terms.shape[1]), -1, jnp.int32)])
-        doc_weights = jnp.concatenate(
-            [doc_weights, jnp.zeros((pad, doc_weights.shape[1]),
-                                    doc_weights.dtype)])
-    return doc_terms, doc_weights, n_tiles
+    pad = ((0, n_tiles * tile_n - n), (0, 0))
+    doc_terms = jnp.pad(doc_terms, pad, constant_values=-1)
+    doc_weights = jnp.pad(doc_weights, pad)
+    return doc_terms.T, doc_weights.T, n_tiles
 
 
 def _lexical_kernel(qt_ref, qw_ref, dt_ref, dw_ref, vals_ref, idx_ref, *,
@@ -139,7 +142,7 @@ def lexical_score(q_terms: jax.Array, q_weights: jax.Array,
     q_weights = q_weights.astype(jnp.float32)
     doc_terms, doc_weights, n_tiles = _pad_postings(
         doc_terms.astype(jnp.int32), doc_weights.astype(jnp.float32), tile_n)
-    l_w = doc_terms.shape[1]
+    l_w = doc_terms.shape[0]
 
     vals, idx = pl.pallas_call(
         functools.partial(_lexical_kernel, k=k, tile_n=tile_n),
@@ -147,8 +150,8 @@ def lexical_score(q_terms: jax.Array, q_weights: jax.Array,
         in_specs=[
             pl.BlockSpec((b, t_q), lambda i: (0, 0)),      # query terms resident
             pl.BlockSpec((b, t_q), lambda i: (0, 0)),
-            pl.BlockSpec((tile_n, l_w), lambda i: (i, 0)),  # postings stream
-            pl.BlockSpec((tile_n, l_w), lambda i: (i, 0)),
+            pl.BlockSpec((l_w, tile_n), lambda i: (0, i)),  # postings stream
+            pl.BlockSpec((l_w, tile_n), lambda i: (0, i)),
         ],
         out_specs=[
             pl.BlockSpec((b, k), lambda i: (0, 0)),        # running top-k

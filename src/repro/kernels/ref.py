@@ -73,9 +73,9 @@ def lexical_score_ref(q_terms: jax.Array, q_weights: jax.Array,
     q_weights = q_weights.astype(jnp.float32)
     doc_terms, doc_weights, n_tiles = _pad_postings(
         doc_terms.astype(jnp.int32), doc_weights.astype(jnp.float32), tile_n)
-    l_w = doc_terms.shape[1]
-    dt = doc_terms.reshape(n_tiles, tile_n, l_w)
-    dw = doc_weights.reshape(n_tiles, tile_n, l_w)
+    l_w = doc_terms.shape[0]
+    dt = doc_terms.reshape(l_w, n_tiles, tile_n).transpose(1, 0, 2)
+    dw = doc_weights.reshape(l_w, n_tiles, tile_n).transpose(1, 0, 2)
     bases = jnp.arange(n_tiles, dtype=jnp.int32) * tile_n
 
     def body(carry, tile):
@@ -104,10 +104,12 @@ def fused_rerank_ref(queries: jax.Array, pool_ids: jax.Array,
     kl = pool_ids.shape[1] - kd
     fuse = functools.partial(_fuse_scores, kd=kd, kl=kl, rrf_k=rrf_k,
                              diversify_sim=diversify_sim)
+    ids = pool_ids.astype(jnp.int32)
     mass, rscore = jax.lax.map(
-        lambda x: fuse(x[0], x[1], x[2]),
-        (queries.astype(jnp.float32), pool_ids.astype(jnp.int32),
-         pool_vecs.astype(jnp.float32)))
+        lambda x: fuse(*x),
+        (queries.astype(jnp.float32)[:, None, :], ids[:, None, :],
+         ids[:, :, None], pool_vecs.astype(jnp.float32)))
+    mass, rscore = mass[:, 0], rscore[:, 0]
     return _final_topk(mass, rscore, pool_ids, k)
 
 

@@ -56,10 +56,12 @@ unfinished cloud dispatch is hedged onto a free worker.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import tempfile
+from typing import Any
 
 
-def main(argv=None) -> None:
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", type=int, default=2000)
     ap.add_argument("--dataset", default="granola",
@@ -165,7 +167,16 @@ def main(argv=None) -> None:
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--h-max", type=int, default=5000)
     ap.add_argument("--entities", type=int, default=20000)
+    ap.add_argument("--dim", type=int, default=64,
+                    help="embedding width of the synthetic corpus (768 is "
+                         "the paper's encoder width)")
     ap.add_argument("--seed", type=int, default=0)
+    return ap
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the CLI; ``fault_plan`` comes back parsed."""
+    ap = _parser()
     args = ap.parse_args(argv)
 
     # fail fast on invalid combinations instead of a downstream shape error
@@ -274,44 +285,94 @@ def main(argv=None) -> None:
                  "self-healing machinery only engages under a non-empty "
                  "fault plan; a fault-free run is bit-identical without "
                  "it)")
-    fault_plan = None
+    if args.dim < 2 or args.dim % 2:
+        ap.error(f"--dim must be even and >= 2 (got {args.dim})")
     if args.fault_plan is not None:
         from repro.serving.faults import FaultPlan
         try:
-            fault_plan = FaultPlan.parse(args.fault_plan)
+            args.fault_plan = FaultPlan.parse(args.fault_plan)
         except ValueError as e:
             ap.error(f"--fault-plan: {e}")
-    workers = 2 if args.workers is None else args.workers
+    return args
 
-    import jax.numpy as jnp
-    import numpy as np
 
+@dataclasses.dataclass
+class ServeStack:
+    """Everything ``main`` serves with, built from parsed arguments."""
+    world: Any
+    svc: Any
+    engine: Any
+    queries: list
+    arrivals: Any          # open-loop arrival times (--engine sched) or None
+    n_agentic: int
+
+
+def _workers(args) -> int:
+    return 2 if args.workers is None else args.workers
+
+
+def _has_config(args, world):
     from repro.core.has import HasConfig
-    from repro.data.synthetic import DATASETS, SyntheticWorld, WorldConfig
-    from repro.retrieval.service import (LocalFlatBackend, ReplicaBackend,
-                                         ShardedMeshBackend)
-    from repro.serving.engine import (ANNSEngine, CRAGEngine,
-                                      FullRetrievalEngine, HasEngine,
-                                      ReuseEngine, RetrievalService)
-    from repro.serving.latency import LatencyModel
+    return HasConfig(k=args.k, tau=args.tau, h_max=args.h_max, nprobe=16,
+                     n_buckets=2048, d=world.cfg.d)
 
-    world = SyntheticWorld(WorldConfig(n_entities=args.entities,
-                                       seed=args.seed))
+
+def _sharded_backend(doc_emb, args, latency):
+    """``ShardedMeshBackend`` on a real mesh when the process has at least
+    ``--shards`` devices (the host corpus placed row-wise over them), else
+    the single-device emulation of the same merge."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.retrieval.service import ShardedMeshBackend
+    devices = jax.devices()
+    mesh = None
+    if len(devices) >= args.shards:
+        mesh = jax.make_mesh((1, args.shards), ("data", "model"),
+                             devices=devices[:args.shards])
+        corpus = jax.device_put(
+            doc_emb, NamedSharding(mesh, P(("data", "model"))))
+        print(f"[serve] sharded backend: corpus on a {args.shards}-device "
+              f"mesh ({devices[0].platform})")
+    else:
+        corpus = jax.numpy.asarray(doc_emb)
+        print(f"[serve] sharded backend: {args.shards} shards emulated on "
+              f"one device ({len(devices)} < --shards devices)")
+    return ShardedMeshBackend(corpus, args.k, latency, n_shards=args.shards,
+                              n_workers=_workers(args), mesh=mesh)
+
+
+def build_world(args):
+    """The seeded synthetic corpus and its oracle (host arrays)."""
+    from repro.data.synthetic import SyntheticWorld, WorldConfig
+    return SyntheticWorld(WorldConfig(n_entities=args.entities, d=args.dim,
+                                      seed=args.seed))
+
+
+def build_service(args, world):
+    """Place the corpus and wrap the ``--retrieval-backend`` in a
+    ``RetrievalService`` (compiles the backend's search once)."""
+    import jax.numpy as jnp
+
+    from repro.retrieval.service import LocalFlatBackend, ReplicaBackend
+    from repro.serving.engine import RetrievalService
+    from repro.serving.latency import LatencyModel
     latency = LatencyModel()
-    corpus = jnp.asarray(world.doc_emb)
+    workers = _workers(args)
+    # the flat and sharded paths place the corpus themselves: a spare
+    # device copy here would cost a whole corpus of device memory
+    corpus = (None if args.retrieval_backend in ("flat", "sharded")
+              else jnp.asarray(world.doc_emb))
     if args.retrieval_backend == "sharded":
-        backend = ShardedMeshBackend(corpus, args.k, latency,
-                                     n_shards=args.shards,
-                                     n_workers=workers)
+        backend = _sharded_backend(world.doc_emb, args, latency)
     elif args.retrieval_backend == "replica":
         from repro.checkpoint import CheckpointManager
         from repro.serving.replication import WarmStandby
-        cfg0 = HasConfig(k=args.k, tau=args.tau, h_max=args.h_max,
-                         nprobe=16, n_buckets=2048, d=world.cfg.d)
         standbys = [
-            WarmStandby(cfg0, CheckpointManager(tempfile.mkdtemp(
-                prefix=f"has-standby{i}-")), snapshot_every=10_000,
-                max_lag=50_000, n_tenants=args.tenants)
+            WarmStandby(_has_config(args, world), CheckpointManager(
+                tempfile.mkdtemp(prefix=f"has-standby{i}-")),
+                snapshot_every=10_000, max_lag=50_000,
+                n_tenants=args.tenants)
             for i in range(workers)]
         backend = ReplicaBackend(
             LocalFlatBackend(corpus, args.k, latency), standbys, corpus)
@@ -340,7 +401,15 @@ def main(argv=None) -> None:
                         if args.hybrid_dense == "ann" else None))
     else:
         backend = None                       # RetrievalService default: flat
-    svc = RetrievalService(world, latency, k=args.k, backend=backend)
+    return RetrievalService(world, latency, k=args.k, backend=backend)
+
+
+def build_stream(args, world) -> tuple[list, int]:
+    """The seeded query stream (tenant tags and agentic hop-1 sub-queries
+    included) -> (queries, number of complex queries)."""
+    import numpy as np
+
+    from repro.data.synthetic import DATASETS
     ds = DATASETS[args.dataset]
     queries = world.sample_queries(
         args.queries, pattern=ds["pattern"], zipf_a=ds["zipf_a"],
@@ -376,29 +445,35 @@ def main(argv=None) -> None:
                 tenants=[int(queries[i].get("tenant", 0)) for i in slots])
             for i, q in zip(slots, hop1):
                 queries[int(i)] = q
+    return queries, n_agentic
 
+
+def build_engine(args, svc):
+    """The ``--engine`` over ``svc`` (the scheduler builds its IVF index and
+    compiles its programs here).  Raises ``ValueError`` when the fault plan
+    does not fit the topology."""
+    from repro.serving.engine import (ANNSEngine, CRAGEngine,
+                                      FullRetrievalEngine, HasEngine,
+                                      ReuseEngine)
+    world = svc.world
     if args.engine == "has":
-        engine = HasEngine(svc, HasConfig(
-            k=args.k, tau=args.tau, h_max=args.h_max,
-            nprobe=16, n_buckets=2048, d=world.cfg.d),
-            n_tenants=args.tenants)
-    elif args.engine == "full":
-        engine = FullRetrievalEngine(svc)
-    elif args.engine in ("proximity", "saferadius", "mincache"):
-        engine = ReuseEngine(svc, args.engine, h_max=args.h_max)
-    elif args.engine == "crag":
-        engine = CRAGEngine(svc, HasConfig(
-            k=args.k, tau=args.tau, h_max=args.h_max,
-            nprobe=16, n_buckets=2048, d=world.cfg.d),
-            n_tenants=args.tenants)
-    elif args.engine == "sched":
-        from repro.serving.edge_pool import DEFAULT_EDGE_SYNC_EVERY
-        from repro.serving.scheduler import (ContinuousBatchingScheduler,
-                                             SchedulerConfig,
-                                             poisson_arrivals)
-        mk = lambda: ContinuousBatchingScheduler(
-            svc, HasConfig(k=args.k, tau=args.tau, h_max=args.h_max,
-                           nprobe=16, n_buckets=2048, d=world.cfg.d),
+        return HasEngine(svc, _has_config(args, world),
+                         n_tenants=args.tenants)
+    if args.engine == "full":
+        return FullRetrievalEngine(svc)
+    if args.engine in ("proximity", "saferadius", "mincache"):
+        return ReuseEngine(svc, args.engine, h_max=args.h_max)
+    if args.engine == "crag":
+        return CRAGEngine(svc, _has_config(args, world),
+                          n_tenants=args.tenants)
+    if args.engine != "sched":
+        return ANNSEngine(svc, method=args.engine)
+    from repro.serving.edge_pool import DEFAULT_EDGE_SYNC_EVERY
+    from repro.serving.scheduler import (ContinuousBatchingScheduler,
+                                         SchedulerConfig)
+    try:
+        return ContinuousBatchingScheduler(
+            svc, _has_config(args, world),
             SchedulerConfig(
                 n_tenants=args.tenants, edge_replicas=args.edge_replicas,
                 edge_sync_every=(DEFAULT_EDGE_SYNC_EVERY
@@ -406,25 +481,47 @@ def main(argv=None) -> None:
                                  else args.edge_sync_every),
                 slo_deadline_s=args.slo_deadline,
                 overload_policy=args.overload_policy,
-                fault_plan=fault_plan,
+                fault_plan=args.fault_plan,
                 **({} if args.retry_max is None
                    else {"retry_max": args.retry_max}),
                 **({} if args.hedge_after is None
                    else {"hedge_after": args.hedge_after})))
-        try:
-            engine = mk()
-        except ValueError as e:
-            # fault-plan vs topology mismatch (bad worker/replica target,
-            # every worker crashed permanently, ...) — surface as a CLI
-            # error, not a traceback
-            ap.error(f"--fault-plan: {e}")
-    else:
-        engine = ANNSEngine(svc, method=args.engine)
+    except ValueError as e:
+        # fault-plan vs topology mismatch (bad worker/replica target,
+        # every worker crashed permanently, ...)
+        raise ValueError(f"--fault-plan: {e}") from e
 
+
+def build(args: argparse.Namespace) -> ServeStack:
+    """World, retrieval service, query stream and engine for ``args``.
+
+    Raises ``ValueError`` when the fault plan does not fit the topology."""
+    from repro.serving.scheduler import poisson_arrivals
+    world = build_world(args)
+    svc = build_service(args, world)
+    queries, n_agentic = build_stream(args, world)
+    engine = build_engine(args, svc)
+    arrivals = None
+    if args.engine == "sched" and args.qps is not None:
+        arrivals = poisson_arrivals(len(queries), qps=args.qps,
+                                    seed=args.seed + 3)
+    return ServeStack(world=world, svc=svc, engine=engine, queries=queries,
+                      arrivals=arrivals, n_agentic=n_agentic)
+
+
+def main(argv=None) -> None:
+    import numpy as np
+
+    args = parse_args(argv)
+    try:
+        stack = build(args)
+    except ValueError as e:
+        # surface a configuration mismatch as a CLI error, not a traceback
+        _parser().error(str(e))
+    engine, queries, svc = stack.engine, stack.queries, stack.svc
+    n_agentic = stack.n_agentic
     if args.engine == "sched":
-        arrivals = (None if args.qps is None else poisson_arrivals(
-            len(queries), qps=args.qps, seed=args.seed + 3))
-        result = engine.serve(queries, arrivals, dataset=args.dataset,
+        result = engine.serve(queries, stack.arrivals, dataset=args.dataset,
                               seed=args.seed)
     else:
         result = engine.serve(queries, dataset=args.dataset, seed=args.seed)
@@ -462,4 +559,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils import use_compile_cache
+    use_compile_cache()
     main()

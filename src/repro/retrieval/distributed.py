@@ -27,9 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils import shard_map
-
-from repro.retrieval.flat import chunked_flat_search
+from repro.retrieval.flat import EXACT_PRECISION, chunked_flat_search
 
 
 def _pad_candidates(s: jax.Array, i: jax.Array, k: int):
@@ -61,7 +59,9 @@ def distributed_flat_search(mesh: Mesh, corpus_axes: tuple[str, ...] = ("data", 
 
         def local(corpus_blk, q):
             # corpus_blk: [N/shards, d] local slice
-            s, i = jax.lax.top_k(q @ corpus_blk.T, min(k, corpus_blk.shape[0]))
+            s, i = jax.lax.top_k(
+                jnp.dot(q, corpus_blk.T, precision=EXACT_PRECISION),
+                min(k, corpus_blk.shape[0]))
             # global ids: offset by this shard's row start
             idx = jax.lax.axis_index(axes)
             i = i + (idx * shard_rows).astype(i.dtype)
@@ -74,7 +74,7 @@ def distributed_flat_search(mesh: Mesh, corpus_axes: tuple[str, ...] = ("data", 
             ts, ti = jax.lax.top_k(s_all, k)
             return ts, jnp.take_along_axis(i_all, ti, axis=1)
 
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axes), P()),
             out_specs=(P(), P()),

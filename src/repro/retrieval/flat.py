@@ -14,6 +14,13 @@ import jax.numpy as jnp
 
 from repro.utils import constrain
 
+# Exact search scores in full f32.  At DEFAULT precision a TPU may run an
+# f32 matmul as a single bf16 pass (~3 significant digits), which reorders
+# near-tied neighbours of an exact scan.  A query batch does B flops per
+# corpus byte, so the extra passes are expected to hide under the corpus
+# read.  The CPU computes in f32 either way.
+EXACT_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def flat_search(corpus: jax.Array, queries: jax.Array, k: int,
                 rules=None, merge_chunks: int = 0) -> tuple[jax.Array, jax.Array]:
@@ -65,7 +72,7 @@ def chunked_flat_search(corpus: jax.Array, queries: jax.Array, k: int,
     def body(carry, inputs):
         best_s, best_i = carry
         block, base = inputs
-        s = queries @ block.T                         # [B, chunk]
+        s = jnp.dot(queries, block.T, precision=EXACT_PRECISION)   # [B, chunk]
         ids = base + jnp.arange(chunk, dtype=jnp.int32)[None, :]
         s = jnp.where(ids < n, s, -jnp.inf)
         cs = jnp.concatenate([best_s, s], axis=1)

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -130,13 +131,42 @@ def kmeans(vecs: jax.Array, n_clusters: int, iters: int = 10,
 _assign_fn = jax.jit(lambda corpus, cents: jnp.argmax(corpus @ cents.T, axis=1))
 
 
+@functools.partial(jax.jit, static_argnames=("block",))
+def _bucket_gather(corpus, ids, block: int):
+    """Corpus rows by bucket slot ([C, cap, d]), zero for pad (-1) slots.
+
+    ``block`` buckets at a time are written in place into the output, so
+    the only transient is one block: a whole-index gather would hold a
+    second bucket-sized buffer for its relayout."""
+    rows = jnp.where(ids >= 0, ids, corpus.shape[0])     # out of range -> 0
+
+    def body(i, out):
+        blk = jax.lax.dynamic_slice_in_dim(rows, i * block, block)
+        vecs = corpus.at[blk].get(mode="fill", fill_value=0)
+        return jax.lax.dynamic_update_slice_in_dim(out, vecs, i * block, 0)
+
+    out = jnp.zeros(ids.shape + corpus.shape[1:], corpus.dtype)
+    return jax.lax.fori_loop(0, ids.shape[0] // block, body, out)
+
+
+_ASSIGN_CHUNK = 262144      # corpus rows per bucket-assignment program
+
+
 def build_ivf(corpus: jax.Array, n_buckets: int, capacity_factor: float = 2.0,
               kmeans_iters: int = 10, seed: int = 0) -> IVFIndex:
-    """Assign every corpus vector to its nearest centroid bucket."""
+    """Assign every corpus vector to its nearest centroid bucket.
+
+    Assignment runs ``_ASSIGN_CHUNK`` rows at a time, so the transient
+    [rows, C] score matrix stays bounded (a 1M-row corpus at C=2048 would
+    otherwise need 8 GB of it).  The buckets are gathered on the device,
+    so the corpus never round-trips through the host.
+    """
     n, d = corpus.shape
     n_buckets = max(1, min(n_buckets, n // 8))   # clamp for tiny corpora
     cents = kmeans(corpus, n_buckets, kmeans_iters, seed)
-    assign = np.asarray(_assign_fn(corpus, cents))
+    assign = np.concatenate([
+        np.asarray(_assign_fn(corpus[lo:lo + _ASSIGN_CHUNK], cents))
+        for lo in range(0, n, _ASSIGN_CHUNK)])
     cap = int(np.ceil(n / n_buckets * capacity_factor))
     # vectorized bucket fill: sort by bucket, position-in-bucket via offsets
     order = np.argsort(assign, kind="stable")
@@ -147,13 +177,12 @@ def build_ivf(corpus: jax.Array, n_buckets: int, capacity_factor: float = 2.0,
     bucket_ids = np.full((n_buckets, cap), -1, np.int32)
     bucket_ids[sorted_b[keep], pos[keep]] = order[keep]
     counts = np.bincount(sorted_b[keep], minlength=n_buckets).astype(np.int32)
-    corpus_np = np.asarray(corpus)
-    safe = np.where(bucket_ids >= 0, bucket_ids, 0)
-    bucket_vecs = corpus_np[safe]
-    bucket_vecs[bucket_ids < 0] = 0.0
+    bucket_ids = jnp.asarray(bucket_ids)
     return IVFIndex(centroids=cents,
-                    bucket_vecs=jnp.asarray(bucket_vecs),
-                    bucket_ids=jnp.asarray(bucket_ids),
+                    bucket_vecs=_bucket_gather(jnp.asarray(corpus),
+                                               bucket_ids,
+                                               block=math.gcd(n_buckets, 64)),
+                    bucket_ids=bucket_ids,
                     bucket_counts=jnp.asarray(counts))
 
 

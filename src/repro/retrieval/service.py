@@ -644,7 +644,13 @@ class RetrievalService:
         self.chunk = min(chunk, world.cfg.n_docs)
         # one device-resident corpus: reuse the backend's copy when one was
         # injected (every backend holds the same world.doc_emb by contract)
+        # and it sits on one device — the edge side (IVF build, cache-ingest
+        # gathers) runs single-device programs, while a mesh backend keeps
+        # its own row shards
         bc = getattr(backend, "corpus", None) if backend is not None else None
+        sharding = getattr(bc, "sharding", None)
+        if sharding is not None and len(sharding.device_set) > 1:
+            bc = None
         self.corpus = bc if bc is not None else jnp.asarray(world.doc_emb)
         self.backend = backend if backend is not None else LocalFlatBackend(
             self.corpus, k, latency, chunk=self.chunk)

@@ -169,9 +169,9 @@ import numpy as np
 import warnings
 
 from repro.core.has import (HasConfig, cache_update_batched,
-                            cache_update_chunked, init_has_state,
-                            init_tenant_states, intra_batch_share,
-                            speculate_batch)
+                            cache_update_chunked, default_backend,
+                            init_has_state, init_tenant_states,
+                            intra_batch_share, speculate_batch)
 from repro.core.homology import reidentify
 from repro.retrieval.ivf import build_ivf
 from repro.serving.edge_pool import DEFAULT_EDGE_SYNC_EVERY, EdgeReplicaPool
@@ -646,6 +646,9 @@ class ContinuousBatchingScheduler:
             raise ValueError(
                 f"tenant_quota must be >= 1 (or None), got "
                 f"{self.sched.tenant_quota}")
+        # speculation path, resolved once: the Pallas kernels on a TPU, the
+        # XLA oracle elsewhere (unless the config names one)
+        self.spec_backend = sc.backend or default_backend()
         self.state = self._init_state()
         self.index = index if index is not None else build_ivf(
             service.corpus, self.cfg.n_buckets, seed=seed)
@@ -750,7 +753,7 @@ class ContinuousBatchingScheduler:
                      else jnp.zeros((sc.max_spec_batch,), jnp.int32))
         jax.block_until_ready(speculate_batch(
             self.cfg, self.state, self.index,
-            jnp.zeros((sc.max_spec_batch, d)), backend=sc.backend,
+            jnp.zeros((sc.max_spec_batch, d)), backend=self.spec_backend,
             tenant_ids=spec_tids))
         scratch = self._init_state()            # donated, then discarded
         jax.block_until_ready(cache_update_batched(
@@ -1157,7 +1160,8 @@ class ContinuousBatchingScheduler:
             # version — a stale replica can only accept drafts its cache
             # actually supports (no phantom accepts)
             out = speculate_batch(self.cfg, spec_state, self.index,
-                                  jnp.asarray(embs), backend=sc.backend,
+                                  jnp.asarray(embs),
+                                  backend=self.spec_backend,
                                   tenant_ids=spec_tids)
             accepts = np.asarray(out["accept"])
             drafts = np.asarray(out["draft_ids"])

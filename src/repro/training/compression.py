@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils import shard_map
 
 
 def quantize_int8(x: jax.Array, axis=None):
@@ -64,9 +63,9 @@ def make_compressed_allreduce(mesh: Mesh, dp_axes=("pod",)):
     def one(g, e):
         def inner(g, e):
             return compressed_psum(g, dp_axes, e)
-        return shard_map(inner, mesh=mesh,
-                         in_specs=(P(dp_axes), P(dp_axes)),
-                         out_specs=(P(), P(dp_axes)))(g, e)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(P(dp_axes), P(dp_axes)),
+                             out_specs=(P(), P(dp_axes)))(g, e)
 
     def allreduce(grads, errors):
         out = jax.tree.map(one, grads, errors)

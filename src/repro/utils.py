@@ -11,7 +11,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
+import os
+from pathlib import Path
 from typing import Any, Mapping, Sequence
 
 import jax
@@ -19,31 +20,28 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # ---------------------------------------------------------------------------
-# Version-compatible shard_map
+# Persistent compilation cache
 # ---------------------------------------------------------------------------
 
-try:                                     # newer jax exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                      # older releases: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma in a
-# different release than the top-level export, so probe the signature
-# instead of inferring the spelling from the import location
-_REP_KWARG = ("check_vma" if "check_vma" in
-              inspect.signature(_shard_map).parameters else "check_rep")
+_REPO_ROOT = Path(__file__).resolve().parents[2]
 
 
-def shard_map(f, *, check_vma: bool | None = None, **kw):
-    """`jax.shard_map` across jax versions.
+def use_compile_cache() -> str:
+    """Keep compiled programs across processes; returns the cache directory.
 
-    Newer jax exports ``jax.shard_map`` and spells the replication-check
-    kwarg ``check_vma``; older versions live in ``jax.experimental`` and
-    spell it ``check_rep``.  Callers always use the new spelling.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``: the directory is part of what a later run
+    must find, so it never depends on a temp dir, a pid or the time.
+    Entry points call this before their first compile.
     """
-    if check_vma is not None:
-        kw[_REP_KWARG] = check_vma
-    return _shard_map(f, **kw)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 # ---------------------------------------------------------------------------
 # Logical axis rules
