@@ -509,8 +509,24 @@ def build(args: argparse.Namespace) -> ServeStack:
                       arrivals=arrivals, n_agentic=n_agentic)
 
 
+def phases_line(requests: int) -> str:
+    """The ``[phases]`` line: calls, total and longest host ms of each
+    ``dispatch.span`` since the last ``dispatch.reset``, then the recorded
+    dispatches a request."""
+    from repro.core import dispatch
+    n = max(requests, 1)
+    return " ".join(
+        ["[phases]"]
+        + [f"{k}={s.calls}/{s.total_ns * 1e-6:.3f}/{s.max_ns * 1e-6:.3f}ms"
+           for k, s in dispatch.spans().items()]
+        + ["dispatches/request"]
+        + [f"{k}={v / n:.4f}" for k, v in dispatch.counts().items()])
+
+
 def main(argv=None) -> None:
     import numpy as np
+
+    from repro.core import dispatch
 
     args = parse_args(argv)
     try:
@@ -520,6 +536,7 @@ def main(argv=None) -> None:
         _parser().error(str(e))
     engine, queries, svc = stack.engine, stack.queries, stack.svc
     n_agentic = stack.n_agentic
+    dispatch.reset()
     if args.engine == "sched":
         result = engine.serve(queries, stack.arrivals, dataset=args.dataset,
                               seed=args.seed)
@@ -535,6 +552,7 @@ def main(argv=None) -> None:
              if n_agentic else ""))
     for k, v in result.summary().items():
         print(f"  {k:20s} {v:.4f}")
+    print(phases_line(len(result.accepts)))
     trace = getattr(result, "trace", None)
     if trace is not None and trace.n:
         print("  per-stage breakdown (virtual-clock seconds):")

@@ -122,6 +122,7 @@ class LocalFlatBackend(_BackendBase):
         self.n_workers = 1
 
     def search(self, q_embs):
+        dispatch.record("flat_backend_search")
         return self._search(self.corpus, q_embs)
 
     def latency(self, batch: int) -> float:
@@ -167,6 +168,7 @@ class ShardedMeshBackend(_BackendBase):
         self.n_workers = max(1, int(n_workers))
 
     def search(self, q_embs):
+        dispatch.record("sharded_backend_search")
         return self._search(self.corpus, q_embs)
 
     def latency(self, batch: int) -> float:
@@ -688,10 +690,12 @@ class RetrievalService:
                            np.asarray(q_terms)[None],
                            None if q_term_weights is None else
                            np.asarray(q_term_weights)[None])
-        s, ids = self.backend.search(jnp.asarray(q_emb)[None], **kw)
-        ids = np.asarray(ids[0])
-        t = self.backend.latency(1)
-        return ids, np.asarray(self.corpus[ids]), t
+        with dispatch.span("has.scan"):
+            _, ids = self.backend.search(jnp.asarray(q_emb)[None], **kw)
+            ids = np.asarray(ids[0])
+        with dispatch.span("has.gather"):
+            vecs = np.asarray(self.corpus[ids])
+        return ids, vecs, self.backend.latency(1)
 
     def full_search_batch(self, q_embs, q_terms=None,
                           q_term_weights=None) -> tuple[np.ndarray, float]:

@@ -36,6 +36,7 @@ from repro.core.baselines import (CRAGEvaluator, ReuseState, init_reuse_state,
                                   mincache_match, minhash_signature,
                                   proximity_match, reuse_insert,
                                   saferadius_match)
+from repro.core.dispatch import span
 from repro.core.has import (HasConfig, cache_update, init_has_state,
                             init_tenant_states, speculate_batch)
 from repro.data.synthetic import SyntheticWorld, simulate_response_accuracy
@@ -245,6 +246,7 @@ class HasEngine(ServeLoop):
         self.fallback = fallback
         self.backend = backend                  # None -> auto per platform
         self.fuzzy_scope = (self.cfg.nprobe / self.cfg.n_buckets) * fuzzy_fraction
+        self.n_steps = 0                        # requests through step()
         # warmup the fused speculation program at the sequential shape B=1
         z = jnp.zeros((1, self.s.world.cfg.d))
         out = speculate_batch(self.cfg, self.state, self.index, z,
@@ -271,43 +273,55 @@ class HasEngine(ServeLoop):
 
     def step(self, q_emb: np.ndarray, tenant: int = 0, q_terms=None,
              q_term_weights=None):
-        """Returns (ids, accept, latency_s, homology)."""
-        lat = self.s.latency.sample_edge()
-        t0 = time.perf_counter()
-        out = speculate_batch(self.cfg, self.state, self.index,
-                              jnp.asarray(q_emb)[None], backend=self.backend,
-                              tenant_ids=self._tids(tenant))
-        jax.block_until_ready(out)
-        # measured edge compute (cache channel + validation at true scale)
-        # + analytic fuzzy scan extrapolated to the target corpus
-        lat += (time.perf_counter() - t0) + self._fuzzy_time()
-        accept = bool(out["accept"][0])
-        if accept:
-            return np.asarray(out["draft_ids"][0]), True, lat, \
-                float(out["homology"][0])
-        # fallback: full database (cloud) or optimized ANNS (♦)
-        if self.fallback is not None:
-            ids, t = self.fallback.search(q_emb)
-            vecs = np.asarray(self.s.corpus[ids])
+        """Returns (ids, accept, latency_s, homology).
+
+        Each phase runs in a ``dispatch.span`` (``has.*``) inside the
+        request's ``has.step``, whose ``req`` is this engine's step count;
+        the step's own time outside them is the latency model's draws."""
+        req, self.n_steps = self.n_steps, self.n_steps + 1
+        with span("has.step", req=req):
+            lat = self.s.latency.sample_edge()
+            t0 = time.perf_counter()
+            with span("has.upload"):
+                q = jnp.asarray(q_emb)[None]
+            with span("has.spec"):
+                out = speculate_batch(self.cfg, self.state, self.index, q,
+                                      backend=self.backend,
+                                      tenant_ids=self._tids(tenant))
+                jax.block_until_ready(out)
+            # measured edge compute (cache channel + validation at true
+            # scale) + analytic fuzzy scan extrapolated to the target corpus
+            lat += (time.perf_counter() - t0) + self._fuzzy_time()
+            with span("has.readback"):
+                accept = bool(out["accept"][0])
+                homology = float(out["homology"][0])
+                draft = np.asarray(out["draft_ids"][0]) if accept else None
+            if accept:
+                return draft, True, lat, homology
+            # fallback: full database (cloud) or optimized ANNS (♦)
+            if self.fallback is not None:
+                ids, t = self.fallback.search(q_emb)
+                vecs = np.asarray(self.s.corpus[ids])
+            else:
+                ids, vecs, t = self.s.full_search(q_emb, q_terms,
+                                                  q_term_weights)
             lat += self.s.latency.sample_cloud() + t
-        else:
-            ids, vecs, t = self.s.full_search(q_emb, q_terms,
-                                              q_term_weights)
-            lat += self.s.latency.sample_cloud() + t
-        t0 = time.perf_counter()
-        self.state = cache_update(self.cfg, self.state, jnp.asarray(q_emb),
-                                  jnp.asarray(ids.astype(np.int32)),
-                                  jnp.asarray(vecs),
-                                  tenant_id=(None if self.n_tenants == 1
-                                             else tenant))
-        jax.block_until_ready(self.state.q_ptr)
-        lat += time.perf_counter() - t0
-        # replica-style backends mirror the ingest onto standby delta logs
-        self.s.backend.on_ingest(
-            np.asarray(q_emb)[None], ids.astype(np.int32)[None], self.state,
-            tenant_ids=(None if self.n_tenants == 1
-                        else np.array([tenant], np.int32)))
-        return ids, False, lat, float(out["homology"][0])
+            t0 = time.perf_counter()
+            with span("has.ingest"):
+                self.state = cache_update(
+                    self.cfg, self.state, jnp.asarray(q_emb),
+                    jnp.asarray(ids.astype(np.int32)), jnp.asarray(vecs),
+                    tenant_id=None if self.n_tenants == 1 else tenant)
+                jax.block_until_ready(self.state.q_ptr)
+            lat += time.perf_counter() - t0
+            # replica-style backends mirror the ingest onto standby delta logs
+            with span("has.replicate"):
+                self.s.backend.on_ingest(
+                    np.asarray(q_emb)[None], ids.astype(np.int32)[None],
+                    self.state,
+                    tenant_ids=(None if self.n_tenants == 1
+                                else np.array([tenant], np.int32)))
+            return ids, False, lat, homology
 
     def _step(self, q, rng, dataset):
         ids, accept, lat, _ = self.step(q["emb"],

@@ -17,7 +17,6 @@ comparison (the paper's evaluation axis) intact.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -144,13 +143,3 @@ class LatencyModel:
     def sample_edge(self) -> float:
         return float(self._rng.uniform(*self.edge_rtt))
 
-
-class Timer:
-    """Wall-clock of a block of device work (block_until_ready outside)."""
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *a):
-        self.elapsed = time.perf_counter() - self.t0
