@@ -264,6 +264,7 @@ def one_chip(entities: int, queries: int, qps: float) -> None:
         corpus=gb(svc.corpus.nbytes),
         ivf=gb(engine.index.bucket_vecs.nbytes),
         state=gb(sum(a.nbytes for a in jax.tree.leaves(engine.state))))
+    log("ivf", bucket_vecs_format=engine.index.bucket_vecs.format)
 
     # the chip path, not a fallback: Pallas kernels, compiled by Mosaic
     if default_backend() != "pallas" or engine.spec_backend != "pallas":
@@ -271,16 +272,17 @@ def one_chip(entities: int, queries: int, qps: float) -> None:
     if auto_interpret():
         raise CheckFailed("Pallas kernels would run in interpret mode")
     cfg, sc = engine.cfg, engine.sched
-    hlo = _speculate_batch_impl.lower(
+    compiled = _speculate_batch_impl.lower(
         cfg, engine.state, engine.index,
         np.zeros((sc.max_spec_batch, cfg.d), np.float32), backend="pallas",
-        interpret=False, tile_c=1024).compile().as_text()
-    n_custom = hlo.count('custom_call_target="tpu_custom_call"')
+        interpret=False, tile_c=1024).compile()
+    n_custom = compiled.as_text().count('custom_call_target="tpu_custom_call"')
     if n_custom < 3:
         raise CheckFailed(f"speculation program holds {n_custom} "
                           "tpu_custom_call(s), expected 3")
     log("pallas", backend=engine.spec_backend, interpret=False,
-        tpu_custom_calls=n_custom)
+        tpu_custom_calls=n_custom,
+        temp=gb(compiled.memory_analysis().temp_size_in_bytes))
 
     queries_, result = serve_stream(args, world, engine, meter)
     check_full_channel(world, queries_, result, cfg.k)

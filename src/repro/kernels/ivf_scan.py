@@ -13,7 +13,30 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Format, Layout
 from jax.experimental.pallas import tpu as pltpu
+
+
+def bucket_format(shape, dtype, sharding) -> Format | None:
+    """The format the Mosaic call reads a ``[C, cap, d]`` bucket array in:
+    row-major, on ``sharding``.
+
+    Returns None where the backend stores ``shape`` row-major already or
+    has no tiled layouts (CPU), so there is nothing to ask for.  A TPU
+    stores ``f32[2048, 977, 768]`` with the buckets second-minor (977 rows
+    are no whole number of 8-row tiles); a program handed that array
+    relays the whole of it out before the kernel can run.
+    """
+    device = next(iter(sharding.device_set))
+    try:
+        default = Layout.from_pjrt_layout(device.client.get_default_layout(
+            jnp.dtype(dtype), sharding.shard_shape(tuple(shape)), device))
+    except jax.errors.JaxRuntimeError:     # no layouts on this backend
+        return None
+    row_major = tuple(range(len(shape)))
+    if not default.tiling or default.major_to_minor == row_major:
+        return None
+    return Format(Layout(row_major), sharding)
 
 
 def _ivf_kernel(probe_ref, *refs, k: int, scaled: bool):

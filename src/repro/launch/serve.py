@@ -501,12 +501,25 @@ def build(args: argparse.Namespace) -> ServeStack:
     svc = build_service(args, world)
     queries, n_agentic = build_stream(args, world)
     engine = build_engine(args, svc)
+    index = getattr(engine, "index", None)
+    if index is not None:
+        print(index_line(index))
     arrivals = None
     if args.engine == "sched" and args.qps is not None:
         arrivals = poisson_arrivals(len(queries), qps=args.qps,
                                     seed=args.seed + 3)
     return ServeStack(world=world, svc=svc, engine=engine, queries=queries,
                       arrivals=arrivals, n_agentic=n_agentic)
+
+
+def index_line(index) -> str:
+    """The IVF bucket array's shape and stored layout: row-major is what
+    the ``ivf_scan`` kernel reads, so any other order costs every
+    speculation program a relayout of the whole array."""
+    vecs = index.bucket_vecs
+    layout = vecs.format.layout         # None where the backend has none
+    return (f"[serve] ivf bucket_vecs {vecs.dtype}{list(vecs.shape)} "
+            f"major_to_minor={getattr(layout, 'major_to_minor', None)}")
 
 
 def phases_line(requests: int) -> str:

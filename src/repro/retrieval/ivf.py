@@ -11,6 +11,7 @@ oracle.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -19,8 +20,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format
 
-
+from repro.kernels.ivf_scan import bucket_format
 from repro.training.compression import quantize_int8
 
 
@@ -131,8 +133,7 @@ def kmeans(vecs: jax.Array, n_clusters: int, iters: int = 10,
 _assign_fn = jax.jit(lambda corpus, cents: jnp.argmax(corpus @ cents.T, axis=1))
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
-def _bucket_gather(corpus, ids, block: int):
+def _gather_buckets(corpus, ids, block: int):
     """Corpus rows by bucket slot ([C, cap, d]), zero for pad (-1) slots.
 
     ``block`` buckets at a time are written in place into the output, so
@@ -149,6 +150,59 @@ def _bucket_gather(corpus, ids, block: int):
     return jax.lax.fori_loop(0, ids.shape[0] // block, body, out)
 
 
+def _first_slots(vecs, cap: int):
+    return vecs[:, :cap]
+
+
+@functools.lru_cache(maxsize=None)
+def _bucket_program(fn, static: str, out_format: Format | None):
+    """``fn`` as one program that writes its bucket array in ``out_format``
+    (:func:`bucket_format`; None keeps the backend's default layout)."""
+    out = {} if out_format is None else {"out_shardings": out_format}
+    return jax.jit(fn, static_argnames=(static,), **out)
+
+
+@contextlib.contextmanager
+def _no_persistent_cache():
+    """Compile inside without JAX's persistent compilation cache.
+
+    An executable that JAX (0.9) reads back from that cache hands out a
+    non-default output layout under the default layout's name, so a
+    row-major bucket array would reach every later program described as
+    the default one: on a TPU the first speculation call fails on the size
+    mismatch, on the CPU it reads scrambled vectors.
+    """
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _write_buckets(fn, static: str, shape, src, *args, **kwargs):
+    """``fn(src, ...)`` as :func:`_bucket_program`, writing its ``shape``
+    bucket array in :func:`bucket_format` on ``src``'s sharding.  A program
+    that asks for a layout of its own is compiled afresh
+    (:func:`_no_persistent_cache`)."""
+    fmt = bucket_format(shape, src.dtype, src.sharding)
+    program = _bucket_program(fn, static, fmt)
+    if fmt is None:
+        return program(src, *args, **kwargs)
+    with _no_persistent_cache():
+        return program(src, *args, **kwargs)
+
+
+def _bucket_gather(corpus, ids, block: int):
+    """:func:`_gather_buckets` written in the layout ``ivf_scan`` reads."""
+    return _write_buckets(_gather_buckets, "block",
+                          ids.shape + corpus.shape[1:], corpus, ids,
+                          block=block)
+
+
 _ASSIGN_CHUNK = 262144      # corpus rows per bucket-assignment program
 
 
@@ -159,7 +213,9 @@ def build_ivf(corpus: jax.Array, n_buckets: int, capacity_factor: float = 2.0,
     Assignment runs ``_ASSIGN_CHUNK`` rows at a time, so the transient
     [rows, C] score matrix stays bounded (a 1M-row corpus at C=2048 would
     otherwise need 8 GB of it).  The buckets are gathered on the device,
-    so the corpus never round-trips through the host.
+    so the corpus never round-trips through the host, and written in the
+    layout the ``ivf_scan`` kernel reads (:func:`bucket_format`), so no
+    speculation program relays them out.
     """
     n, d = corpus.shape
     n_buckets = max(1, min(n_buckets, n // 8))   # clamp for tiny corpora
@@ -277,8 +333,12 @@ def subset_index(index: IVFIndex, fraction: float, seed: int = 0) -> IVFIndex:
         return index
     cap = index.capacity
     new_cap = max(1, int(cap * fraction))
+    vecs = index.bucket_vecs
     return IVFIndex(centroids=index.centroids,
-                    bucket_vecs=index.bucket_vecs[:, :new_cap],
+                    bucket_vecs=_write_buckets(
+                        _first_slots, "cap",
+                        (index.n_buckets, new_cap) + vecs.shape[2:], vecs,
+                        cap=new_cap),
                     bucket_ids=index.bucket_ids[:, :new_cap],
                     bucket_counts=jnp.minimum(index.bucket_counts, new_cap))
 

@@ -23,14 +23,14 @@ from repro.core.has import (HasConfig, HasState, _speculate_batch_impl,
                             _speculate_batch_tenant_impl)
 from repro.kernels.fused_rerank import fused_rerank
 from repro.kernels.homology_score import homology_score
-from repro.kernels.ivf_scan import ivf_scan
+from repro.kernels.ivf_scan import bucket_format, ivf_scan
 from repro.kernels.lexical_score import lexical_score
 from repro.kernels.topk_search import topk_search
-from repro.retrieval.ivf import IVFIndex
+from repro.retrieval.ivf import IVFIndex, _bucket_program, _gather_buckets
 
 B, D, K = 32, 768, 10                 # speculation batch, width, draft size
 DOC_CAP, H_MAX = 50_000, 5_000        # HasConfig(h_max=5000).doc_cap
-N_BUCKETS, CAP, NPROBE = 2048, 977, 16
+N_DOCS, N_BUCKETS, CAP, NPROBE = 1_000_000, 2048, 977, 16
 POSTINGS, TERMS, Q_TERMS = 1_000_000, 5, 2
 CFG = HasConfig(k=K, tau=0.2, h_max=H_MAX, nprobe=NPROBE,
                 n_buckets=N_BUCKETS, d=D)
@@ -57,8 +57,14 @@ def one_chip():
 
 @pytest.fixture(scope="module")
 def shape(one_chip):
-    """Argument shapes on the described chip, in the row-major layout a
-    device array has by default."""
+    """Argument shapes on the described chip, each in the row-major layout.
+
+    Row-major is not the chip's default for every shape: it stores
+    ``f32[2048, 977, 768]`` with the 2048 buckets second-minor, since 977
+    rows are no whole number of 8-row tiles.  The program compiled here
+    therefore reads an index the build has already written row-major;
+    ``test_speculation_reads_built_index_in_place`` takes that layout from
+    the build program itself."""
     from jax.experimental.layout import Format, Layout
 
     def make(dims, dtype):
@@ -153,6 +159,14 @@ def test_fused_rerank_compiles(shape, dsim):
     assert _kernels(hlo) == ["fused_rerank"]
 
 
+@pytest.fixture(scope="module")
+def stored(one_chip):
+    """Argument shapes on the described chip in the layout the runtime
+    gives a device array of that shape by default."""
+    return lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                    sharding=one_chip)
+
+
 def _state(shape, tenants=None):
     lead = () if tenants is None else (tenants,)
     return HasState(
@@ -165,15 +179,8 @@ def _state(shape, tenants=None):
         d_ptr=shape(lead, jnp.int32))
 
 
-@pytest.mark.parametrize("tenants", [None, 2], ids=["single", "tenants"])
-def test_speculation_program_compiles(shape, tenants):
-    """The whole Pallas speculation program the scheduler warms up: all
-    three kernels are Mosaic custom calls, and its temporaries stay small
-    beside the ~9.4 GB the chip holds resident at this size."""
-    index = IVFIndex(centroids=shape((N_BUCKETS, D), jnp.float32),
-                     bucket_vecs=shape((N_BUCKETS, CAP, D), jnp.float32),
-                     bucket_ids=shape((N_BUCKETS, CAP), jnp.int32),
-                     bucket_counts=shape((N_BUCKETS,), jnp.int32))
+def _speculation(shape, index, tenants):
+    """The Pallas speculation program over ``index``, compiled."""
     q = shape((B, D), jnp.float32)
     common = dict(backend="pallas", interpret=False, tile_c=1024)
     if tenants is None:
@@ -183,7 +190,68 @@ def test_speculation_program_compiles(shape, tenants):
         lowered = jax.jit(lambda st, ix, q, t: _speculate_batch_tenant_impl(
             CFG, st, ix, q, t, **common)).lower(
                 _state(shape, tenants), index, q, shape((B,), jnp.int32))
-    compiled = lowered.compile()
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("tenants", [None, 2], ids=["single", "tenants"])
+def test_speculation_program_compiles(shape, tenants):
+    """The whole Pallas speculation program the scheduler warms up: all
+    three kernels are Mosaic custom calls, and its temporaries stay small
+    beside the ~9.4 GB the chip holds resident at this size."""
+    index = IVFIndex(centroids=shape((N_BUCKETS, D), jnp.float32),
+                     bucket_vecs=shape((N_BUCKETS, CAP, D), jnp.float32),
+                     bucket_ids=shape((N_BUCKETS, CAP), jnp.int32),
+                     bucket_counts=shape((N_BUCKETS,), jnp.int32))
+    compiled = _speculation(shape, index, tenants)
     assert sorted(_kernels(compiled.as_text())) == [
         "homology_score", "ivf_scan", "topk_search"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
+
+
+@pytest.mark.parametrize("cap,dtype,asked", [
+    (CAP, jnp.float32, True), (CAP, jnp.int8, True),
+    (984, jnp.float32, False)], ids=["f32", "int8", "whole_tiles"])
+def test_bucket_format_on_v5e(one_chip, cap, dtype, asked):
+    """Row-major is asked for where the chip's default stores the buckets
+    second-minor (977 rows are no whole number of tiles), and nothing
+    where the default is row-major already."""
+    fmt = bucket_format((N_BUCKETS, cap, D), dtype, one_chip)
+    if asked:
+        assert fmt.layout.major_to_minor == (0, 1, 2)
+        assert fmt.sharding == one_chip
+    else:
+        assert fmt is None
+
+
+@pytest.fixture(scope="module")
+def built_vecs(one_chip, stored):
+    """The bucket array as ``build_ivf``'s gather writes it at 1M x 768:
+    its format read from the compiled build program, not assumed."""
+    fmt = bucket_format((N_BUCKETS, CAP, D), jnp.float32, one_chip)
+    assert fmt is not None and fmt.layout.major_to_minor == (0, 1, 2)
+    gather = _bucket_program(_gather_buckets, "block", fmt).lower(
+        stored((N_DOCS, D), jnp.float32),
+        stored((N_BUCKETS, CAP), jnp.int32), block=64).compile()
+    assert gather.memory_analysis().temp_size_in_bytes < 512 * 2**20
+    return jax.ShapeDtypeStruct((N_BUCKETS, CAP, D), jnp.float32,
+                                sharding=gather.output_formats)
+
+
+@pytest.mark.parametrize("tenants", [None, 2], ids=["single", "tenants"])
+def test_speculation_reads_built_index_in_place(stored, built_vecs, tenants):
+    """Handed the bucket array in the format the build wrote, and every
+    other argument in the runtime's default layout, the speculation
+    program holds no relayout copy of the 6.1 GB array, and its
+    temporaries stay small."""
+    assert built_vecs.format.layout.major_to_minor == (0, 1, 2)
+    index = IVFIndex(centroids=stored((N_BUCKETS, D), jnp.float32),
+                     bucket_vecs=built_vecs,
+                     bucket_ids=stored((N_BUCKETS, CAP), jnp.int32),
+                     bucket_counts=stored((N_BUCKETS,), jnp.int32))
+    compiled = _speculation(stored, index, tenants)
+    hlo = compiled.as_text()
+    assert sorted(_kernels(hlo)) == [
+        "homology_score", "ivf_scan", "topk_search"]
+    assert not re.search(
+        rf"= f32\[{N_BUCKETS},{CAP},{D}\]\{{[^}}]*\}} copy\(", hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
