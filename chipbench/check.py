@@ -1,6 +1,7 @@
 """The comparison that decides ``correct``.
 
-Five numbers, each against its limit in ``limits.json``:
+Each number against its limit in ``limits.json``; a path that shares no
+answers has no ``follow_*`` numbers:
 
 ``scan_gap``
     Over the window's requests answered by the exact cloud scan (all of
@@ -13,16 +14,23 @@ Five numbers, each against its limit in ``limits.json``:
     document it returned it for.  It reads the scan's precision whether or
     not a near-tie is there to be swapped.
 ``ingest_bad``
-    Rows folded into the cache that are not exactly what was served (a
-    served full-scan or shared result missing, extra, or altered), plus
-    rows of the program's final cache state that differ, bit for bit, from
-    a host replay of those rows.  Exact: limit 0.
+    In every cache lifetime (one for a single long-running cache, one a
+    ``serve`` for a path that starts each from empty): rows folded into
+    the cache that are not exactly what the cloud answered and was served
+    (a served full-scan or shared result missing, extra, repeated or
+    altered); plus rows of the cache each lifetime left that differ, bit
+    for bit, from a host replay of its rows from an empty cache (the last
+    one's final state whole, an earlier one's ids, validity and
+    pointers).  Exact: limit 0.
 ``accept_bad``
     On the cache the window left, for a seeded sample of the window's
     queries sent through the timed speculation program: accept flags and
     homology scores that differ from the reference's homology of the same
     validation draft against the replayed cache, and validation drafts
-    that differ from the served draft.  Exact: limit 0.
+    that differ from the served draft.  Where the path records its
+    speculation, also every window row served a draft that is not its
+    speculation's, or accepted there and served something else
+    (``draft_served_bad``).  Exact: limit 0.
 ``draft_gap``
     For the same sample: the widest gap by which a draft's j-th document
     scores below the reference's lower bound on the j-th best draft.
@@ -33,6 +41,13 @@ Five numbers, each against its limit in ``limits.json``:
     full, an id out of range) plus listed slots whose vector is not the
     row's.  The draft bound takes the table as given once this reads 0.
     Exact: limit 0.
+``follow_bad``
+    Rows served another request's answer whose leader paid no exact scan
+    of its own, whose served set is not the leader's, or whose sharing
+    election fails when recomputed on the host.  Exact: limit 0.
+``follow_gap``
+    The widest gap by which such a row's j-th served document scores, in
+    float64 under its own query, below the j-th best of the leader's set.
 
 With ``control``, the control's readings stand in the compared numbers'
 place (``control_in_place``): the reference one precision below the
@@ -47,6 +62,7 @@ import os
 import numpy as np
 
 from chipbench import reference as ref
+from chipbench.drivers import row_lookup
 
 LIMITS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "limits.json")
@@ -59,18 +75,128 @@ def load_limits(config: dict, path: str = LIMITS_FILE) -> dict:
     return limits
 
 
-def ingest_consistency(recorded_q, recorded_ids, emb: np.ndarray,
-                       served: np.ndarray, accepts: np.ndarray) -> int:
-    """The ingests must be the rejected requests' (query, served ids), in
-    order."""
-    rej = np.flatnonzero(~accepts)
-    want_q, want_ids = emb[rej], served[rej]
-    n = min(len(rej), len(recorded_q))
-    bad = abs(len(rej) - len(recorded_q))
-    if n:
-        bad += int(((recorded_q[:n] != want_q[:n]).any(axis=1)
-                    | (recorded_ids[:n] != want_ids[:n]).any(axis=1)).sum())
-    return bad
+def ingest_consistency(life, emb: np.ndarray, ids: np.ndarray,
+                       cloud: np.ndarray) -> int:
+    """Recorded ingests of one cache lifetime that are not exactly its
+    cloud-answered rows (query and served ids), each once, in any order:
+    rows missing, extra, repeated or altered."""
+    rows = life.rows[life.preloaded:]
+    want = rows[cloud[rows]]
+    at = row_lookup(emb, want)
+    seen: set[int] = set()
+    bad = 0
+    for q, i in zip(life.ingest_q, life.ingest_ids):
+        r = at.get(q.tobytes())
+        if r is None or r in seen or not np.array_equal(i, ids[r]):
+            bad += 1
+        else:
+            seen.add(r)
+    return bad + len(want) - len(seen)
+
+
+def ingest_numbers(lives, emb, ids, cloud, state_np: dict, corpus_np,
+                   has: dict) -> tuple[dict, ref.CacheReplay]:
+    """``ingest_bad`` over every cache lifetime: its recorded ingests
+    against its cloud-answered rows, and the lifetime replayed on the host
+    from an empty cache (its bulk-folded rows, then its recorded ingests in
+    program order) against the cache it left, bit for bit: the fields its
+    ``end`` holds, and the program's final state whole for the last.
+    Returns the numbers and the last lifetime's replay."""
+    bad_rows = sum(ingest_consistency(life, emb, ids, cloud)
+                   for life in lives)
+    diff: dict = {}
+    for j, life in enumerate(lives):
+        last = j == len(lives) - 1
+        if not last and life.end is None:
+            continue
+        if not last and not life.end:
+            diff["cache_reused"] = diff.get("cache_reused", 0) + 1
+            continue
+        cache = ref.CacheReplay(has["h_max"], has["k"], has["doc_capacity"],
+                                emb.shape[1])
+        pre = life.rows[:life.preloaded]
+        for q, i in zip(np.concatenate([emb[pre], life.ingest_q]),
+                        np.concatenate([ids[pre], life.ingest_ids])):
+            cache.ingest(q, i)
+        got = ref.state_mismatch(cache, state_np if last else life.end,
+                                 corpus_np)
+        for key, v in got.items():
+            diff[key] = diff.get(key, 0) + v
+    return dict(diff, ingest_bad=bad_rows + sum(diff.values()),
+                ingest_consistency_bad=bad_rows,
+                ingest_rows=len(lives[-1].ingest_q),
+                lifetimes=len(lives)), cache
+
+
+def draft_served_bad(drafted: np.ndarray, served: np.ndarray,
+                     spec: dict) -> int:
+    """Rows served a draft that is not their recorded speculation's, rows
+    whose speculation accepted but that were served something else, rows
+    speculated other than once, and speculated rows that are no
+    request's."""
+    bad = drafted & (served != spec["draft_ids"]).any(axis=1)
+    bad |= spec["accept"] & ~drafted
+    bad |= spec["seen"] != 1
+    return int(bad.sum()) + spec["stray"]
+
+
+def _low_order(queries, corpus_np, ids) -> np.ndarray:
+    """``ids`` re-ranked by one bfloat16 pass: bfloat16 operands, products
+    summed in float64 (no rounding there): the control of a float32
+    re-rank."""
+    import ml_dtypes
+
+    def bf(x):
+        return np.asarray(x, np.float32).astype(ml_dtypes.bfloat16).astype(
+            np.float64)
+    s = np.einsum("rkd,rd->rk", bf(corpus_np[np.maximum(ids, 0)]),
+                  bf(queries))
+    s = np.where(ids >= 0, s, -np.inf)
+    return np.take_along_axis(ids, np.argsort(-s, axis=1, kind="stable"),
+                              axis=1)
+
+
+def follow_numbers(emb, served, leader, exact, spec: dict, tau: float,
+                   corpus_np, control: bool = False) -> dict:
+    """``follow_bad`` and ``follow_gap`` over every row served another
+    row's answer (``leader`` >= 0).
+
+    ``follow_bad`` counts followers whose leader did not pay its own exact
+    scan, whose served set is not the leader's, whose speculation accepted,
+    or whose election fails when recomputed: the share of the follower's
+    validation draft ids that the leader's draft holds, in float32, not
+    above ``tau``.  ``follow_gap``: the widest gap by which a follower's
+    j-th served document scores, in float64 under its own query, below the
+    j-th best of its leader's served set."""
+    f = np.flatnonzero(leader >= 0)
+    out = {"follow_rows": len(f)}
+    if not len(f):
+        out.update(follow_bad=0, follow_gap=0.0)
+        if control:
+            out["control_bf16_follow_gap"] = 0.0
+        return out
+    lead = leader[f]
+    k = served.shape[1]
+    bad = ~exact[lead]
+    bad |= (np.sort(served[f], axis=1) != np.sort(served[lead], axis=1)).any(
+        axis=1)
+    vf, vl = spec["val_ids"][f], spec["val_ids"][lead]
+    overlap = ((vf[:, :, None] == vl[:, None, :]).any(axis=2)
+               & (vf >= 0)).sum(axis=1)
+    bad |= ~(overlap.astype(np.float32) / np.float32(k) > np.float32(tau))
+    bad |= spec["accept"][f] | (spec["seen"][f] != 1)
+    q = emb[f]
+    best = np.sort(ref.scores64(corpus_np, q, served[lead]), axis=1)[:, ::-1]
+    gap = ref.shortfall(best, served[f], ref.scores64(corpus_np, q, served[f]))
+    out.update(follow_bad=int(bad.sum()), follow_gap=float(gap.max()),
+               follow_election_bad=int((overlap.astype(np.float32)
+                                        / np.float32(k)
+                                        <= np.float32(tau)).sum()))
+    if control:
+        low = _low_order(q, corpus_np, served[lead])
+        cgap = ref.shortfall(best, low, ref.scores64(corpus_np, q, low))
+        out["control_bf16_follow_gap"] = float(cgap.max())
+    return out
 
 
 def scan_numbers(corpus, corpus_np, queries, served, k: int,
@@ -170,7 +296,10 @@ def ivf_numbers(corpus, corpus_np, centroids, bucket_ids, vecs_wrong: int,
 # compared number -> the control reading that takes its place
 CONTROL_FOR = {"scan_gap": "control_high_scan_gap",
                "score_err": "control_high_score_err",
-               "draft_gap": "control_int8_draft_gap"}
+               "draft_gap": "control_int8_draft_gap",
+               "follow_gap": "control_bf16_follow_gap"}
+COMPARED = ("scan_gap", "score_err", "draft_gap", "accept_bad", "ingest_bad",
+            "ivf_bad", "follow_bad", "follow_gap")
 
 
 def control_in_place(numbers: dict) -> dict:
@@ -178,15 +307,14 @@ def control_in_place(numbers: dict) -> dict:
     program's own kept as ``program_<name>``."""
     out = dict(numbers)
     for name, ctl in CONTROL_FOR.items():
-        out[f"program_{name}"] = numbers[name]
-        out[name] = numbers[ctl]
+        if name in numbers:
+            out[f"program_{name}"] = numbers[name]
+            out[name] = numbers[ctl]
     return out
 
 
 def verdict(numbers: dict, limits: dict) -> tuple[bool, dict]:
     """(every compared number within its limit, {name: {value, limit}})."""
     shown = {name: {"value": numbers[name], "limit": limits[name]}
-             for name in ("scan_gap", "score_err", "draft_gap", "accept_bad",
-                          "ingest_bad", "ivf_bad")
-             if name in numbers}
+             for name in COMPARED if name in numbers}
     return all(v["value"] <= v["limit"] for v in shown.values()), shown

@@ -20,14 +20,29 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.drivers import Warm, Window
+from chipbench.drivers import Lifetime, Warm, Window, stack_rows
 
 ENGINE = "has"
 LOAD = "closed loop, one client: lateness is the host gap between a reply and the next send"
 
 
+def install(probe, engine) -> None:
+    """Wrap the entries ``HasEngine.step`` calls."""
+    from repro.serving import engine as loop
+    probe.patch(loop, "speculate_batch", "spec")
+    # cache_update(cfg, state, q_emb [d], full_ids [k], full_vecs, ...)
+    probe.patch(loop, "cache_update", "ingest",
+                record=lambda args, kwargs, out: (args[2], args[3]))
+    probe.patch(engine.s.backend, "search", "cloud_scan",
+                record=lambda args, kwargs, out: (args[0], out))
+
+
 def spec_batch(engine) -> int:
     return 1
+
+
+def spec_backend(engine):
+    return engine.backend
 
 
 def warm(engine, stream, traffic) -> Warm:
@@ -53,8 +68,10 @@ def warm(engine, stream, traffic) -> Warm:
         ids.append(got)
         acc.append(accept)
         i += 1
-    return Warm(ids=np.concatenate([bulk, np.stack(ids)]),
+    acc = np.array(acc, bool)
+    return Warm(rows=np.arange(i), ids=np.concatenate([bulk, np.stack(ids)]),
                 accepts=np.concatenate([np.zeros(w, bool), acc]),
+                cloud=np.concatenate([np.ones(w, bool), ~acc]),
                 preloaded=w)
 
 
@@ -89,7 +106,7 @@ def window(engine, stream, start: int, seconds: float, traffic,
     return Window(
         rows=start + np.arange(n), n=n, wall_s=wall, served=ids[:n],
         accepts=acc[:n],
-        exact_rows=np.flatnonzero(~acc[:n]),
+        exact_rows=np.flatnonzero(~acc[:n]), cloud=~acc[:n],
         spec_calls=n, spec_rows=n, scan_calls=rejected, scan_rows=rejected,
         e2e={"latency_mean_ms": 1e3 * wall / n,
              "latency_p95_ms": 1e3 * float(np.percentile(lat, 95))},
@@ -103,3 +120,14 @@ def window(engine, stream, start: int, seconds: float, traffic,
 
 def final_state(engine):
     return engine.state
+
+
+def lifetimes(probe, warm: Warm, win: Window, emb) -> list[Lifetime]:
+    """One cache for the whole run: set-up's bulk fold, then every
+    rejected request's ingest, in stream order."""
+    kept = probe.kept("ingest")
+    d, k = emb.shape[1], warm.ids.shape[1]
+    return [Lifetime(rows=np.arange(len(warm.rows) + win.n),
+                     ingest_q=stack_rows([q for q, _ in kept], d, np.float32),
+                     ingest_ids=stack_rows([i for _, i in kept], k, np.int32),
+                     preloaded=warm.preloaded)]

@@ -105,11 +105,16 @@ def device_topk_all(corpus, queries: np.ndarray, k: int, mode: str,
 
 
 def scores64(corpus_np: np.ndarray, queries: np.ndarray,
-             ids: np.ndarray) -> np.ndarray:
-    """float64 ``<q_r, corpus[ids[r, j]]>``; -inf where an id is invalid."""
+             ids: np.ndarray, block: int = 2048) -> np.ndarray:
+    """float64 ``<q_r, corpus[ids[r, j]]>``; -inf where an id is invalid.
+    ``block`` rows at a time, so that the float64 copies stay small."""
     ok = (ids >= 0) & (ids < len(corpus_np))
-    rows = corpus_np[np.where(ok, ids, 0)].astype(np.float64)
-    s = np.einsum("rkd,rd->rk", rows, queries.astype(np.float64))
+    safe = np.where(ok, ids, 0)
+    s = np.empty(ids.shape, np.float64)
+    for lo in range(0, len(ids), block):
+        rows = corpus_np[safe[lo:lo + block]].astype(np.float64)
+        s[lo:lo + block] = np.einsum(
+            "rkd,rd->rk", rows, queries[lo:lo + block].astype(np.float64))
     return np.where(ok, s, -np.inf)
 
 
@@ -123,10 +128,16 @@ def exact_topk(corpus, corpus_np: np.ndarray, queries: np.ndarray, k: int,
     ids = np.take_along_axis(ci, order, axis=1)
     top = np.take_along_axis(s64, order, axis=1)
     doubtful = np.flatnonzero(top[:, -1] - cs[:, -1] <= margin)
-    for r in doubtful:
-        full = corpus_np.astype(np.float64) @ queries[r].astype(np.float64)
-        o = np.argsort(-full, kind="stable")[:k]
-        ids[r], top[r] = o, full[o]
+    if len(doubtful):
+        q64 = queries[doubtful].astype(np.float64)
+        full = np.empty((len(doubtful), len(corpus_np)), np.float64)
+        step = row_chunk(len(corpus_np))
+        for lo in range(0, len(corpus_np), step):
+            full[:, lo:lo + step] = q64 @ corpus_np[lo:lo + step].astype(
+                np.float64).T
+        o = np.argsort(-full, axis=1, kind="stable")[:, :k]
+        ids[doubtful] = o
+        top[doubtful] = np.take_along_axis(full, o, axis=1)
     return ids, top, len(doubtful)
 
 
@@ -264,15 +275,18 @@ class CacheReplay:
 def state_mismatch(ref: CacheReplay, got: dict, corpus_np) -> dict:
     """Rows where a program cache state (host arrays named as HasState's
     fields) differs from the replay, bit for bit; pointer mismatches
-    count one each."""
+    count one each.  A state without the vector fields (``query_emb``,
+    ``doc_emb``) is compared on the others."""
     valid = ref.query_valid
     q_rows = ((got["query_valid"] != valid)
-              | (got["query_doc_ids"] != ref.query_ids).any(axis=1)
-              | (valid & (got["query_emb"] != ref.query_emb).any(axis=1)))
+              | (got["query_doc_ids"] != ref.query_ids).any(axis=1))
+    if "query_emb" in got:
+        q_rows |= valid & (got["query_emb"] != ref.query_emb).any(axis=1)
     held = ref.doc_ids >= 0
-    emb = ref.doc_emb(corpus_np)
-    d_rows = ((got["doc_ids"] != ref.doc_ids)
-              | (held & (got["doc_emb"] != emb).any(axis=1)))
+    d_rows = got["doc_ids"] != ref.doc_ids
+    if "doc_emb" in got:
+        d_rows |= held & (got["doc_emb"] != ref.doc_emb(corpus_np)).any(
+            axis=1)
     return {"query_rows": int(q_rows.sum()), "doc_rows": int(d_rows.sum()),
             "pointers": int(int(got["q_ptr"]) != ref.q_ptr)
             + int(int(got["d_ptr"]) != ref.d_ptr)}

@@ -4,10 +4,13 @@
         --trace <0|1>
 
 A cell (``BENCHMARK.json`` ``workloads``) is a deployment configuration
-(``configs/*.json``) under a traffic mix (``traffic/*.json``).  The run
-makes the corpus and the query stream on the device from ``--seed``, builds
-the serving path through ``launch/serve.py``'s builders, warms it up (all
-of which is ``setup_s``), then serves for ``--seconds`` on the host clock.
+(``configs/*.json``) under a traffic mix (``traffic/*.json``), whose
+``path`` names the driver module of its serving path (``drivers/``): the
+engine it builds, the attributes its loop calls, how it warms up and
+serves, and what each cache lifetime took in.  The run makes the corpus
+and the query stream on the device from ``--seed``, builds the engine
+through ``launch/serve.py``'s builders, warms it up (all of which is
+``setup_s``), then serves for ``--seconds`` on the host clock.
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` records
 the window with the profiler and prints its per-layer metrics.  Every run
 then compares what the window served with the plain reference
@@ -119,14 +122,13 @@ def _check_build(engine, config: dict) -> None:
                          f"states {has}, d={config['d']}")
 
 
-def _speculate_sample(engine, q, batch: int) -> dict:
+def _speculate_sample(engine, q, batch: int, backend) -> dict:
     """The timed speculation program, at the window's batch size, on the
     cache state the window left."""
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core.has import speculate_batch
-    backend = engine.backend
     out = {key: [] for key in ("accept", "homology", "val_ids", "draft_ids")}
     for lo in range(0, len(q), batch):
         block = np.zeros((batch, q.shape[1]), np.float32)
@@ -139,10 +141,29 @@ def _speculate_sample(engine, q, batch: int) -> dict:
     return {key: np.concatenate(v) for key, v in out.items()}
 
 
+def _window_spec(lives, rows: np.ndarray, k: int) -> dict | None:
+    """The recorded speculation of the served table's ``rows`` (None where
+    the path records none), gathered from the lifetimes that hold them."""
+    import numpy as np
+    if any(life.spec is None for life in lives):
+        return None
+    n = int(max(int(life.rows.max()) for life in lives)) + 1
+    tab = {"val_ids": np.full((n, k), -1, np.int32),
+           "draft_ids": np.full((n, k), -1, np.int32),
+           "accept": np.zeros(n, bool), "seen": np.zeros(n, np.int32)}
+    for life in lives:
+        for key in tab:
+            tab[key][life.rows] = life.spec[key]
+    out = {key: v[rows] for key, v in tab.items()}
+    out["stray"] = sum(life.spec["stray"] for life in lives)
+    return out
+
+
 def run(cell_name: str, seed: int, seconds: float, trace: bool,
         control: bool = False, *, bench=None, config=None, traffic=None,
         peaks=None, chips_required: bool = True, cache: bool = True,
-        trace_dir: str = TRACE_DIR, t0: float = T0) -> dict:
+        trace_dir: str = TRACE_DIR, t0: float = T0,
+        driver_dir: str | None = None) -> dict:
     """One run of one cell; returns the result object."""
     import jax
     import numpy as np
@@ -163,7 +184,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     if cache:
         use_cache()
     meter = CompileMeter()
-    drv = drivers.load(traffic["path"])
+    drv = drivers.load(traffic["path"], driver_dir or drivers.HERE)
 
     # -- set-up: world, program, warm-up -----------------------------------
     t = time.perf_counter()
@@ -189,11 +210,11 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
         resident_bytes=mem["bytes_in_use"], limit_bytes=mem["bytes_limit"])
 
     probe = spans.Probe(trace)
-    probe.install(traffic["path"], engine)
+    drv.install(probe, engine)
     t = time.perf_counter()
     warm = drv.warm(engine, stream, traffic)
-    start = len(warm.ids)
-    log("warm", requests=start, preloaded=warm.preloaded,
+    start = int(warm.rows.max()) + 1
+    log("warm", requests=len(warm.rows), preloaded=warm.preloaded,
         host_s=f"{time.perf_counter() - t:.2f}",
         dar=f"{warm.accepts[warm.preloaded:].mean():.4f}")
     cs = meter.snapshot()
@@ -215,6 +236,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     if trace:
         jax.profiler.stop_trace()
     cw = meter.snapshot()
+    span_names = probe.span_names
     mem = _hbm(device)
     peak = mem["peak_bytes_in_use"]
     acc = win.accepts
@@ -235,20 +257,28 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     sample = np.sort(rng.choice(win.n, min(SPEC_SAMPLE, win.n),
                                 replace=False))
     sample_q = stream.emb[win.rows[sample]]
-    prog = _speculate_sample(engine, sample_q, drv.spec_batch(engine))
+    prog = _speculate_sample(engine, sample_q, drv.spec_batch(engine),
+                             drv.spec_backend(engine))
     state = drv.final_state(engine)
     state_np = {f: np.asarray(getattr(state, f)) for f in STATE_FIELDS}
     centroids = np.asarray(engine.index.centroids)
     bucket_ids = np.asarray(engine.index.bucket_ids)
     vecs_wrong = reference.bucket_vecs_wrong(
         w.doc_emb, engine.index.bucket_vecs, engine.index.bucket_ids)
-    rec_q, rec_ids = probe.ingest_rows()
-    scan_q, scan_s, scan_ids = probe.scan_rows()
-    scan_q = scan_q.reshape(-1, stream.emb.shape[1])
-    rec_q = rec_q.reshape(-1, stream.emb.shape[1])
-    rec_ids = rec_ids.reshape(-1, config["has"]["k"])
+    k, d = config["has"]["k"], config["d"]
+    # the served table: set-up's rows, then the window's
+    table_emb = stream.emb[np.concatenate([warm.rows, win.rows])]
+    table_ids = np.concatenate([warm.ids, win.served])
+    table_cloud = np.concatenate([warm.cloud, win.cloud])
+    lives = drv.lifetimes(probe, warm, win, table_emb)
+    win_rows = len(warm.rows) + np.arange(win.n)
+    spec_rec = _window_spec(lives, win_rows, k)
+    scans = probe.kept("cloud_scan", window=True)
+    scan_q = drivers.stack_rows([q for q, _ in scans], d, np.float32)
+    scan_s = drivers.stack_rows([o[0] for _, o in scans], k, np.float32)
+    scan_ids = drivers.stack_rows([o[1] for _, o in scans], k, np.int32)
     probe.uninstall()
-    del engine, svc, state
+    del engine, svc, state, probe
     gc.collect()
 
     # -- the reference -----------------------------------------------------
@@ -256,41 +286,43 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     has = config["has"]
     corpus = w.doc_emb
     corpus_np = np.asarray(corpus)
-    p = warm.preloaded
-    bad_rows = check.ingest_consistency(
-        rec_q, rec_ids, stream.emb[p:start + win.n],
-        np.concatenate([warm.ids[p:], win.served]),
-        np.concatenate([warm.accepts[p:], win.accepts]))
-    cache = reference.CacheReplay(has["h_max"], has["k"],
-                                  has["doc_capacity"], config["d"])
-    for q, ids in zip(np.concatenate([stream.emb[:p], rec_q]),
-                      np.concatenate([warm.ids[:p], rec_ids])):
-        cache.ingest(q, ids)
-    diff = reference.state_mismatch(cache, state_np, corpus_np)
-    numbers = {"ingest_bad": bad_rows + sum(diff.values())}
+    numbers, cache = check.ingest_numbers(lives, table_emb, table_ids,
+                                          table_cloud, state_np, corpus_np,
+                                          has)
+    del lives
     rows = win.exact_rows
     if len(rows) > MAX_SCAN_ROWS:
         rows = np.sort(rng.choice(rows, MAX_SCAN_ROWS, replace=False))
     # the answers folded into the cache in bulk in set-up: a seeded sample
+    p = warm.preloaded
     pre = (np.sort(rng.choice(p, min(WARM_SAMPLE, p), replace=False))
            if p else np.zeros(0, int))
     numbers.update(check.scan_numbers(
         corpus, corpus_np,
-        np.concatenate([stream.emb[pre], stream.emb[win.rows[rows]]]),
-        np.concatenate([warm.ids[pre], win.served[rows]]), has["k"],
+        np.concatenate([table_emb[pre], stream.emb[win.rows[rows]]]),
+        np.concatenate([table_ids[pre], win.served[rows]]), has["k"],
         control))
     numbers.update(check.score_numbers(corpus_np, scan_q, scan_s, scan_ids))
     numbers.update(check.spec_numbers(sample_q, prog, cache, corpus_np,
                                       centroids, bucket_ids, has,
                                       control=control))
+    if spec_rec is not None:
+        served_bad = check.draft_served_bad(win.drafted, win.served, spec_rec)
+        numbers.update(draft_served_bad=served_bad,
+                       accept_bad=numbers["accept_bad"] + served_bad)
+    if win.leader is not None:
+        exact = np.zeros(win.n, bool)
+        exact[win.exact_rows] = True
+        numbers.update(check.follow_numbers(
+            table_emb[win_rows], win.served, win.leader, exact, spec_rec,
+            win.share_tau, corpus_np, control))
     numbers.update(check.ivf_numbers(corpus, corpus_np, centroids,
                                      bucket_ids, vecs_wrong))
     if control:
         numbers = check.control_in_place(numbers)
     correct, shown = check.verdict(numbers, check.load_limits(config))
     log("reference", host_s=f"{time.perf_counter() - t:.2f}",
-        ingest_rows=len(rec_q), ingest_consistency_bad=bad_rows, **diff,
-        **{k: v for k, v in numbers.items() if k not in shown})
+        **{name: v for name, v in numbers.items() if name not in shown})
 
     # -- metrics -----------------------------------------------------------
     hits = world.doc_hits(w, ents[win.rows], attrs[win.rows], win.served)
@@ -310,7 +342,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
                                             "unit": m["unit"]}
     else:
         red = tracing.reduce_file(tracing.latest_xplane(trace_dir),
-                                  spans.SPANS)
+                                  span_names)
         result["device"].update(busy_s=red.busy_s, window_s=red.window_s)
         ctx = types.SimpleNamespace(
             requests=win.n, accepted=int(acc.sum()),

@@ -1,115 +1,105 @@
-"""Host spans, ingest and scan records around the program's layer entries.
+"""Host spans and records around the program's layer entries.
 
-The benchmark wraps, from outside, the module attributes through which the
-serving loops call each layer:
+The benchmark wraps, from outside, the attributes through which a serving
+loop calls each layer.  Which attributes, under which span names, is the
+driver's to say (``drivers/<path>.py::install``, through ``Probe.patch``):
+this module knows no serving path.
 
-==========  ==================================================  ==========
-span        entry point                                         layer
-==========  ==================================================  ==========
-spec        ``speculate_batch``                                 speculation
-cloud_scan  ``RetrievalService.backend.search``                 cloud scan
-ingest      ``cache_update``                                    cache ingest
-==========  ==================================================  ==========
-
-Every run counts the calls and keeps a reference to each ingest's rows
-(the correctness check replays them) and to each cloud scan's queries and
-answers (the check rescores them); only a traced run opens a
-``jax.profiler.TraceAnnotation`` per call and waits for the call's device
-work at span end, so that device events fall inside the span that launched
-them.
+Every run counts each span's calls and keeps what the driver asks it to
+record (references only: device arrays are read back after the window),
+each record tagged with the cache lifetime it fell in and with whether it
+fell in the measured window.  A driver starts a new cache lifetime through
+``new_lifetime`` where its program starts from an empty cache.  Only a
+traced run opens a ``jax.profiler.TraceAnnotation`` per call and waits for
+the call's device work at span end, so that device events fall inside the
+span that launched them.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 
 import jax
-import numpy as np
-
-SPANS = ("spec", "cloud_scan", "ingest")
 
 
 class Probe:
-    """Call counts, ingest rows and scan answers; spans when ``traced``."""
+    """Call counts and records; spans when ``traced``."""
 
     def __init__(self, traced: bool):
         self.traced = traced
-        self.calls = dict.fromkeys(SPANS, 0)
-        self.ingests: list[tuple[np.ndarray, np.ndarray]] = []
-        self.scans: list[tuple] = []     # (queries, (scores, ids))
+        self.calls: dict[str, int] = {}
+        # span -> [(lifetime, in_window, item)]
+        self.records: dict[str, list] = collections.defaultdict(list)
+        self.lifetime = 0
+        self.in_window = False
         self._undo: list = []
 
-    def start_window(self) -> None:
-        """Count and keep scans from here on."""
-        self.calls = dict.fromkeys(SPANS, 0)
-        self.scans = []
+    @property
+    def span_names(self) -> tuple[str, ...]:
+        return tuple(self.calls)
 
-    def _wrap(self, span: str, fn, on_call=None, on_return=None):
+    def start_window(self) -> None:
+        """Count calls from here on; records from here on are the
+        window's."""
+        self.calls = dict.fromkeys(self.calls, 0)
+        self.in_window = True
+
+    def new_lifetime(self, *_) -> None:
+        """The program starts again from an empty cache."""
+        self.lifetime += 1
+
+    def keep(self, key: str, item) -> None:
+        """Record ``item`` under ``key`` outside any call."""
+        self.records[key].append((self.lifetime, self.in_window, item))
+
+    def _wrap(self, span, fn, before, record):
         traced = self.traced
 
         def call(*args, **kwargs):
+            if before is not None:
+                before(args, kwargs)
+            if span is None:
+                return fn(*args, **kwargs)
             self.calls[span] += 1
-            if on_call is not None:
-                on_call(args, kwargs)
             if not traced:
                 out = fn(*args, **kwargs)
             else:
                 with jax.profiler.TraceAnnotation(span):
                     out = fn(*args, **kwargs)
                     jax.block_until_ready(out)
-            if on_return is not None:
-                on_return(args, out)
+            if record is not None:
+                self.keep(span, record(args, kwargs, out))
             return out
         return call
 
-    def patch(self, owner, name: str, span: str, on_call=None,
-              on_return=None) -> None:
+    def patch(self, owner, name: str, span: str | None = None, *,
+              before=None, record=None) -> None:
+        """Wrap ``owner.name``: ``before(args, kwargs)`` first; then, with a
+        ``span``, the call counted (a span in a traced run) and
+        ``record(args, kwargs, out)`` kept under the span's name."""
         old = getattr(owner, name)
-        self._undo.append((owner, name, old))
-        setattr(owner, name, self._wrap(span, old, on_call, on_return))
-
-    def install(self, path: str, engine) -> None:
-        """Wrap the entries the ``seq`` loop (``HasEngine.step``) calls."""
-        if path != "seq":
-            raise ValueError(f"unknown path {path!r}")
-        from repro.serving import engine as loop
-        self.patch(loop, "speculate_batch", "spec")
-        self.patch(loop, "cache_update", "ingest", self._seq_row)
-        self.patch(engine.s.backend, "search", "cloud_scan",
-                   on_return=lambda args, out: self.scans.append(
-                       (args[0], out)))
+        own = name in getattr(owner, "__dict__", {})
+        self._undo.append((owner, name, old, own))
+        if span is not None:
+            self.calls.setdefault(span, 0)
+        setattr(owner, name, self._wrap(span, old, before, record))
 
     def uninstall(self) -> None:
         while self._undo:
-            owner, name, old = self._undo.pop()
-            setattr(owner, name, old)
+            owner, name, old, own = self._undo.pop()
+            if own:
+                setattr(owner, name, old)
+            else:
+                delattr(owner, name)
 
-    # references only: the rows are read back after the window
-    # cache_update(cfg, state, q_emb [d], full_ids [k], full_vecs, ...)
-    def _seq_row(self, args, kwargs):
-        self.ingests.append((args[2], args[3]))
-
-    def scan_rows(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Every kept scan's rows: (queries [n, d], scores [n, k],
-        ids [n, k]); a batch's padding rows included."""
-        if not self.scans:
-            return (np.zeros((0, 0), np.float32),) * 2 + (
-                np.zeros((0, 1), np.int32),)
-        return (np.concatenate([np.asarray(q, np.float32)
-                                for q, _ in self.scans]),
-                np.concatenate([np.asarray(o[0], np.float32)
-                                for _, o in self.scans]),
-                np.concatenate([np.asarray(o[1], np.int32)
-                                for _, o in self.scans]))
-
-    def ingest_rows(self) -> tuple[np.ndarray, np.ndarray]:
-        """All recorded ingest rows, in order: (q [n, d], ids [n, k])."""
-        qs = [np.asarray(q, np.float32).reshape(-1, np.shape(q)[-1])
-              for q, _ in self.ingests]
-        ids = [np.asarray(i, np.int32).reshape(-1, np.shape(i)[-1])
-               for _, i in self.ingests]
-        if not qs:
-            return np.zeros((0, 0), np.float32), np.zeros((0, 0), np.int32)
-        return np.concatenate(qs), np.concatenate(ids)
+    def kept(self, span: str, *, window: bool | None = None,
+             lifetime: int | None = None) -> list:
+        """The items recorded under ``span``, in call order; only the
+        window's (``window=True``) or set-up's (``False``), only one
+        lifetime's."""
+        return [item for life, win, item in self.records.get(span, ())
+                if (window is None or win == window)
+                and (lifetime is None or life == lifetime)]
 
 
 @contextlib.contextmanager

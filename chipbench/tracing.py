@@ -6,7 +6,7 @@ A traced run records the window with ``jax.profiler`` and reads the
 * device work: the events of each device plane (``/device:TPU:n``) on its
   ``XLA Modules`` line (one event per program execution), or on every line
   but the step and module markers where a plane has no such line;
-* host spans: the events named ``window`` and those in ``spans.SPANS`` on
+* host spans: the events named ``window`` and those of the Probe's spans on
   any host thread.
 
 A device plane keeps its own clock, which a TPU trace shows some hundreds

@@ -37,13 +37,26 @@ TINY_TRAFFIC = {
     "seq": {"path": "seq", "dataset": "granola", "zipf_a": 1.12,
             "p_uncovered": 0.42, "rank_seed": 7, "warm_requests": 48,
             "warm_batch": 16, "warm_steps": 8, "stream_requests": 4000},
+    "sched": {"path": "sched", "dataset": "triviaqa", "zipf_a": 1.04,
+              "p_uncovered": 0.05, "rank_seed": 7, "warm_requests": 96,
+              "segment_requests": 320, "segments": 2,
+              "stream_requests": 96 + 2 * 320,
+              "scheduler": {"max_spec_batch": 32, "full_batch": 16,
+                            "ingest_batch": 32, "share": True,
+                            "share_tau": 0.1, "revalidate": True,
+                            "ingest_followers": True,
+                            "follower_score_weighted": True,
+                            "cloud_workers": 1, "edge_replicas": 1,
+                            "n_tenants": 1, "overload_policy": "none",
+                            "fault_plan": None}},
 }
+E2E = {"seq": ["latency_mean_ms", "latency_p95_ms"], "sched": ["qps"]}
 
 
 def tiny_bench(path: str) -> dict:
     """A benchmark of one tiny cell on the ``path`` traffic."""
     name = f"tiny.{path}"
-    e2e = ["latency_mean_ms", "latency_p95_ms"]
+    e2e = E2E.get(path, E2E["seq"])
     return {
         "configs": [{"name": "tiny", "file": "unused"}],
         "workloads": [{"name": name, "config": "tiny",
@@ -68,10 +81,11 @@ def tiny(tmp_path):
 
     def go(path, seed=11, seconds=0.5, trace=False, config=None,
            traffic=None, **kw):
+        base = TINY_TRAFFIC.get(path, TINY_TRAFFIC["seq"])
         return run.run(f"tiny.{path}", seed, seconds, trace,
                        bench=tiny_bench(path),
                        config=copy.deepcopy(config or TINY_CONFIG),
-                       traffic=dict(TINY_TRAFFIC[path], **(traffic or {})),
+                       traffic=dict(base, **(traffic or {})),
                        peaks=work.peaks_for("TPU v5 lite"),
                        chips_required=False, cache=False,
                        trace_dir=str(tmp_path / "trace"), **kw)

@@ -25,7 +25,11 @@ def test_every_cell_resolves(bench):
         cfg = spec.config(bench, cell)
         assert cfg["name"] == cell["config"]
         tr = spec.traffic(cell["traffic"])
-        assert drivers.load(tr["path"]).ENGINE == "has"
+        drv = drivers.load(tr["path"])
+        assert drv.ENGINE in ("has", "sched")
+        for fn in ("install", "warm", "window", "spec_batch", "spec_backend",
+                   "final_state", "lifetimes"):
+            assert callable(getattr(drv, fn)), (tr["path"], fn)
         e2e = {m["name"] for m in spec.end_to_end(bench, cell["name"])}
         assert "setup_s" in e2e and len(e2e) >= 2
         layer = spec.per_layer(bench, cell["name"])
