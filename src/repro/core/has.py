@@ -744,6 +744,9 @@ def cache_update_chunked(cfg: HasConfig, state: HasState, q_embs, full_ids,
         full_vecs = np.asarray(full_vecs, np.float32)
     if tenant_ids is not None:
         tenant_ids = np.asarray(tenant_ids, np.int32)
+    # host chunks go to the jitted programs as numpy arrays: the compiled
+    # call moves them to the device itself, without a ``jnp.asarray``'s
+    # host overhead for each
     for i0 in range(0, n, chunk):
         m = min(chunk, n - i0)
         embs = np.zeros((chunk, d), np.float32)
@@ -752,22 +755,23 @@ def cache_update_chunked(cfg: HasConfig, state: HasState, q_embs, full_ids,
         embs[:m] = q_embs[i0:i0 + m]
         ids[:m] = full_ids[i0:i0 + m]
         mask[:m] = True
-        ids_j = jnp.asarray(ids)
         if full_vecs is None:
-            vecs = corpus[ids_j]
+            vecs = _gather_rows(corpus, ids)
         else:
             vecs = np.zeros((chunk, k, d), np.float32)
             vecs[:m] = full_vecs[i0:i0 + m]
-            vecs = jnp.asarray(vecs)
         tids = None
         if tenant_ids is not None:
             tids = np.zeros((chunk,), np.int32)     # pad rows: tenant 0,
             tids[:m] = tenant_ids[i0:i0 + m]        # masked off anyway
-            tids = jnp.asarray(tids)
-        state = cache_update_batched(cfg, state, jnp.asarray(embs), ids_j,
-                                     vecs, jnp.asarray(mask),
+        state = cache_update_batched(cfg, state, embs, ids, vecs, mask,
                                      tenant_ids=tids)
     return state
+
+
+# ``corpus[ids]`` as one compiled program: eager indexing re-runs JAX's
+# index normalisation on the host (about a millisecond) at every call
+_gather_rows = jax.jit(lambda corpus, ids: corpus[ids])
 
 
 def cache_memory_bytes(cfg: HasConfig) -> int:

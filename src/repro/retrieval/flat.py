@@ -58,33 +58,36 @@ def chunked_flat_search(corpus: jax.Array, queries: jax.Array, k: int,
     """Streaming exact top-k: scans corpus chunks with a running top-k merge.
 
     Bounds the transient score matrix to [B, chunk]; this is the pure-jnp
-    oracle for the Pallas ``topk_search`` kernel.
+    oracle for the Pallas ``topk_search`` kernel.  The corpus is read where
+    it lies: whole chunks are dynamic slices of the stored array, and a
+    ragged tail (``N % chunk`` rows) is scored as a block of its own, so no
+    call copies or pads the corpus.  Ties keep the lower id.
     """
-    n, d = corpus.shape
+    n = corpus.shape[0]
     b = queries.shape[0]
-    n_chunks = max(1, (n + chunk - 1) // chunk)
-    pad = n_chunks * chunk - n
-    if pad:
-        corpus = jnp.concatenate(
-            [corpus, jnp.zeros((pad, d), corpus.dtype)], axis=0)
-    blocks = corpus.reshape(n_chunks, chunk, d)
+    n_full, tail = divmod(n, chunk)
 
-    def body(carry, inputs):
+    def merge(carry, block, base):
         best_s, best_i = carry
-        block, base = inputs
-        s = jnp.dot(queries, block.T, precision=EXACT_PRECISION)   # [B, chunk]
-        ids = base + jnp.arange(chunk, dtype=jnp.int32)[None, :]
-        s = jnp.where(ids < n, s, -jnp.inf)
+        s = jnp.dot(queries, block.T, precision=EXACT_PRECISION)   # [B, rows]
+        ids = base + jnp.arange(block.shape[0], dtype=jnp.int32)
         cs = jnp.concatenate([best_s, s], axis=1)
-        ci = jnp.concatenate([best_i, jnp.broadcast_to(ids, (b, chunk))], axis=1)
+        ci = jnp.concatenate([best_i, jnp.broadcast_to(ids, s.shape)], axis=1)
         ts, ti = jax.lax.top_k(cs, k)
-        return (ts, jnp.take_along_axis(ci, ti, axis=1)), None
+        return ts, jnp.take_along_axis(ci, ti, axis=1)
 
-    init = (jnp.full((b, k), -jnp.inf, queries.dtype),
-            jnp.full((b, k), -1, jnp.int32))
-    bases = (jnp.arange(n_chunks) * chunk).astype(jnp.int32)
-    (scores, ids), _ = jax.lax.scan(body, init, (blocks, bases))
-    return scores, ids
+    def body(j, carry):
+        base = j * chunk
+        block = jax.lax.dynamic_slice_in_dim(corpus, base, chunk, axis=0)
+        return merge(carry, block, base)
+
+    carry = (jnp.full((b, k), -jnp.inf, queries.dtype),
+             jnp.full((b, k), -1, jnp.int32))
+    if n_full:
+        carry = jax.lax.fori_loop(0, n_full, body, carry)
+    if tail:
+        carry = merge(carry, corpus[n_full * chunk:], n_full * chunk)
+    return carry
 
 
 # ---------------------------------------------------------------------------

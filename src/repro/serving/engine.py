@@ -108,9 +108,10 @@ def _record(m, i, world, query, ids, lat, accept, dataset, llms, rng):
     m["accepts"][i] = accept
     m["doc_hits"][i] = hit
     m["correct"][i] = hit and accept
+    n_docs = int(np.count_nonzero(np.asarray(ids) >= 0))
     for llm in llms:
         m["ra"][llm][i] = simulate_response_accuracy(
-            rng, hit, dataset, llm, n_docs=int(np.sum(np.asarray(ids) >= 0)))
+            rng, hit, dataset, llm, n_docs=n_docs)
 
 
 # ---------------------------------------------------------------------------

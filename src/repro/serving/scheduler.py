@@ -168,6 +168,7 @@ import numpy as np
 
 import warnings
 
+from repro.core import dispatch
 from repro.core.has import (HasConfig, cache_update_batched,
                             cache_update_chunked, default_backend,
                             init_has_state, init_tenant_states,
@@ -1078,15 +1079,16 @@ class ContinuousBatchingScheduler:
                 # tenant tags for registry rows + the group + inert padding:
                 # the election masks cross-tenant pairs, so a follower can
                 # only attach to a leader of its own partition
-                share_tids = jnp.asarray(np.concatenate([
+                share_tids = np.concatenate([
                     reg_tenant,
                     np.array([r.tenant for r in group], np.int32),
-                    np.zeros(sc.max_spec_batch - g, np.int32)]))
-            out = intra_batch_share(jnp.asarray(vals), jnp.asarray(rejected),
-                                    jnp.float32(self._share_tau),
-                                    jnp.asarray(pending), share_tids)
-            leader_of = np.asarray(out["leader"])
-            is_leader = np.asarray(out["is_leader"])
+                    np.zeros(sc.max_spec_batch - g, np.int32)])
+            dispatch.record("intra_batch_share")
+            out = intra_batch_share(vals, rejected,
+                                    np.float32(self._share_tau), pending,
+                                    share_tids)
+            leader_of, is_leader = jax.device_get(
+                (out["leader"], out["is_leader"]))
             for j, r in enumerate(group):
                 row = cap + j
                 if is_leader[row]:
@@ -1152,20 +1154,17 @@ class ContinuousBatchingScheduler:
             if T == 1:
                 spec_tids = None
             else:
-                batch_tids = np.zeros(sc.max_spec_batch, np.int32)
+                spec_tids = np.zeros(sc.max_spec_batch, np.int32)
                 for j, r in enumerate(batch):
-                    batch_tids[j] = r.tenant
-                spec_tids = jnp.asarray(batch_tids)
+                    spec_tids[j] = r.tenant
             # acceptance is decided against the SERVING replica's own cache
             # version — a stale replica can only accept drafts its cache
             # actually supports (no phantom accepts)
-            out = speculate_batch(self.cfg, spec_state, self.index,
-                                  jnp.asarray(embs),
+            out = speculate_batch(self.cfg, spec_state, self.index, embs,
                                   backend=self.spec_backend,
                                   tenant_ids=spec_tids)
-            accepts = np.asarray(out["accept"])
-            drafts = np.asarray(out["draft_ids"])
-            val_ids = np.asarray(out["val_ids"])
+            accepts, drafts, val_ids = jax.device_get(
+                (out["accept"], out["draft_ids"], out["val_ids"]))
             spec_s = self._spec_time(len(batch))
             t_done = t + replay_s + spec_s
             for j, r in enumerate(batch):
@@ -1428,15 +1427,15 @@ class ContinuousBatchingScheduler:
                 for j, r in enumerate(batch):
                     vids[j] = r.val_ids
                 if T == 1:
-                    reval_args = (jnp.asarray(vids),)
+                    reval_args = (vids,)
                 else:
                     vtids = np.zeros(sc.full_batch, np.int32)
                     for j, r in enumerate(batch):
                         vtids[j] = r.tenant
-                    reval_args = (jnp.asarray(vids), jnp.asarray(vtids))
+                    reval_args = (vids, vtids)
                 acc = np.asarray(self._revalidate(
                     *reval_args, self.state.query_doc_ids,
-                    self.state.query_valid, jnp.float32(self.cfg.tau))[0])
+                    self.state.query_valid, np.float32(self.cfg.tau))[0])
                 survivors = []
                 for j, r in enumerate(batch):
                     # rerouted-after-replica-crash rows carry sentinel
@@ -1494,7 +1493,7 @@ class ContinuousBatchingScheduler:
                     tws[j, :qw.shape[0]] = qw
                 term_kw = dict(q_terms=jnp.asarray(terms),
                                q_term_weights=jnp.asarray(tws))
-            _, ids_full = self.s.backend.search(jnp.asarray(embs), **term_kw)
+            _, ids_full = self.s.backend.search(embs, **term_kw)
             ids_full = np.asarray(ids_full)
             if not fault_mode:
                 cloud = rtt_rng.uniform(*lat.cloud_rtt) + self._full_time(b)

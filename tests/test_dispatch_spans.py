@@ -119,3 +119,28 @@ def test_serve_cli_prints_phases(capsys):
     assert got["has.spec"].startswith("16/") and got["has.upload"]
     assert float(got["speculate_batch"]) == 1.0
     assert float(got["flat_backend_search"]) == n_scan / 16
+
+
+def test_sched_cli_counts_each_election(capsys, monkeypatch):
+    """The scheduler records one ``intra_batch_share`` dispatch per
+    sharing election it runs while serving (not its warm-up), and the
+    serving CLI prints the count on its ``[phases]`` line."""
+    from repro.launch.serve import main
+    from repro.serving import scheduler
+
+    calls = []
+    elect = scheduler.intra_batch_share
+
+    def counted(*a, **k):
+        calls.append(dispatch.counts().get("intra_batch_share", 0))
+        return elect(*a, **k)
+    monkeypatch.setattr(scheduler, "intra_batch_share", counted)
+    main(["--engine", "sched", "--queries", "64", "--entities", "60",
+          "--dim", "32"])
+    line, = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("[phases]")]
+    got = dict(kv.split("=") for kv in line.split()[1:] if "=" in kv)
+    served = calls[1:]                     # calls[0] is the warm-up's
+    assert served and served == list(range(1, len(served) + 1))
+    assert float(got["intra_batch_share"]) == pytest.approx(
+        len(served) / 64, abs=1e-4)

@@ -25,6 +25,37 @@ def test_chunked_equals_flat(rng):
     assert np.array_equal(np.asarray(i1), np.asarray(i2))
 
 
+SCAN_CHUNK = 128
+
+
+@pytest.mark.parametrize("b", [1, 16])
+@pytest.mark.parametrize("d", [64, 1024])
+@pytest.mark.parametrize("n", [1, SCAN_CHUNK - 1, SCAN_CHUNK, SCAN_CHUNK + 1,
+                               3 * SCAN_CHUNK + 17])
+def test_chunked_scan_matches_float64_topk(n, d, b):
+    """Whole chunks and a ragged tail, read in place, give the exact top-k:
+    the ids of a float64 numpy scan wherever its gap to the next score is
+    above 1e-6, and its scores within 2^-22, the float32 tolerance of a
+    unit-vector inner product.  Past the corpus, ids are -1."""
+    k = 10
+    rng = np.random.default_rng([n, d, b])
+    corpus, q = _unit(rng, n, d), _unit(rng, b, d)
+    s, i = (np.asarray(x) for x in chunked_flat_search(
+        jnp.asarray(corpus), jnp.asarray(q), k, chunk=SCAN_CHUNK))
+    full = q.astype(np.float64) @ corpus.astype(np.float64).T
+    order = np.argsort(-full, axis=1, kind="stable")
+    m = min(k, n)
+    want_i = order[:, :m]
+    want_s = np.take_along_axis(full, want_i, axis=1)
+    nxt = np.take_along_axis(full, order[:, 1:m + 1], axis=1) if n > m \
+        else np.full((b, m), -np.inf)
+    nxt[:, :m - 1] = want_s[:, 1:]
+    clear = want_s - nxt > 1e-6
+    assert np.array_equal(i[:, :m][clear], want_i[clear])
+    np.testing.assert_allclose(s[:, :m], want_s, rtol=0, atol=2.0 ** -22)
+    assert (i[:, m:] == -1).all() and np.isneginf(s[:, m:]).all()
+
+
 @settings(max_examples=20, deadline=None)
 @given(st.integers(0, 1000), st.integers(1, 7), st.sampled_from([64, 100, 257]))
 def test_chunked_property(seed, k, n):

@@ -12,6 +12,7 @@ suite collects the same tests on every worker and only the worker that runs
 this file loads the TPU compiler.  The persistent compilation cache is off
 around them: an entry compiled for a described chip cannot be read back.
 """
+import functools
 import os
 import re
 
@@ -26,6 +27,7 @@ from repro.kernels.homology_score import homology_score
 from repro.kernels.ivf_scan import bucket_format, ivf_scan
 from repro.kernels.lexical_score import lexical_score
 from repro.kernels.topk_search import topk_search
+from repro.retrieval.flat import chunked_flat_search
 from repro.retrieval.ivf import IVFIndex, _bucket_program, _gather_buckets
 
 B, D, K = 32, 768, 10                 # speculation batch, width, draft size
@@ -255,3 +257,17 @@ def test_speculation_reads_built_index_in_place(stored, built_vecs, tenants):
     assert not re.search(
         rf"= f32\[{N_BUCKETS},{CAP},{D}\]\{{[^}}]*\}} copy\(", hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2**20
+
+
+@pytest.mark.parametrize("b", [1, 16])
+@pytest.mark.parametrize("d", [768, 1024])
+def test_exact_scan_reads_corpus_in_place(shape, d, b):
+    """The cloud scan over a 1M-row corpus (no whole number of 32,768-row
+    chunks) reads the stored array: no padded copy of the corpus in its
+    program, and temporaries far below one corpus (3.1 GB at d=768, 4.1 GB
+    at d=1024)."""
+    scan = functools.partial(chunked_flat_search, k=K, chunk=32_768)
+    compiled = jax.jit(scan).lower(shape((N_DOCS, d), jnp.float32),
+                                   shape((b, d), jnp.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+    assert not re.search(r"f32\[1015808,", compiled.as_text())
