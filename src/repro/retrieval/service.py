@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import dispatch
+from repro.core.has import _gather_rows
 from repro.retrieval.distributed import (distributed_flat_search,
                                          sharded_topk_reference)
 from repro.retrieval.flat import chunked_flat_search
@@ -685,16 +686,19 @@ class RetrievalService:
 
     def full_search(self, q_emb: np.ndarray, q_terms=None,
                     q_term_weights=None):
-        """Exact full-database search; returns (ids [k], vecs [k,d], t_comp)."""
+        """Exact full-database search; returns (ids [k] on the host, vecs
+        [k,d] on the device, t_comp): the scan's ids come back in one read,
+        and the rows are gathered by one compiled program."""
         kw = self._term_kw(None if q_terms is None else
                            np.asarray(q_terms)[None],
                            None if q_term_weights is None else
                            np.asarray(q_term_weights)[None])
         with dispatch.span("has.scan"):
-            _, ids = self.backend.search(jnp.asarray(q_emb)[None], **kw)
-            ids = np.asarray(ids[0])
+            _, ids = self.backend.search(np.asarray(q_emb, np.float32)[None],
+                                         **kw)
+            ids = jax.device_get(ids)[0]
         with dispatch.span("has.gather"):
-            vecs = np.asarray(self.corpus[ids])
+            vecs = _gather_rows(self.corpus, ids)
         return ids, vecs, self.backend.latency(1)
 
     def full_search_batch(self, q_embs, q_terms=None,

@@ -1,7 +1,7 @@
 """Spans and dispatch counts of the serving hot path (``core/dispatch.py``):
 each span's calls, total and longest host time, nesting, ``reset``, the
-counts the full-retrieval backends record, and the serving CLI's
-``[phases]`` line that reads them."""
+counts the full-retrieval backends record, what ``HasEngine.step``
+dispatches, and the serving CLI's ``[phases]`` line that reads them."""
 from __future__ import annotations
 
 import types
@@ -10,10 +10,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import dispatch
+from repro.core import dispatch, has
 from repro.data.synthetic import SyntheticWorld, WorldConfig
 from repro.retrieval.service import (LocalFlatBackend, RetrievalService,
                                      ShardedMeshBackend)
+from repro.serving.engine import HasEngine
 from repro.serving.latency import LatencyModel
 
 
@@ -103,6 +104,31 @@ def test_full_search_spans_scan_and_gather(world):
     assert set(dispatch.spans()) == {"has.scan", "has.gather"}
     assert all(s.calls == 1 for s in dispatch.spans().values())
     assert c.counts() == {"flat_backend_search": 1}
+
+
+def test_step_launches_one_program_a_phase(world):
+    """An accepted ``HasEngine.step`` dispatches its speculation alone; a
+    rejected one adds one scan and one ingest.  Once one of each has run,
+    further steps compile nothing: host arrays and device rows reuse the
+    cached programs of the speculation, the row gather and the ingest."""
+    svc = RetrievalService(world, LatencyModel(), k=10, chunk=256)
+    eng = HasEngine(svc, has.HasConfig(k=10, tau=0.2, h_max=64, nprobe=8,
+                                       n_buckets=32, d=world.cfg.d))
+    qs = world.sample_queries(21, seed=3)
+    q = qs[0]["emb"]
+    with dispatch.capture() as c:
+        assert not eng.step(q)[1]           # an empty cache rejects
+    assert c.counts() == {"speculate_batch": 1, "flat_backend_search": 1,
+                          "cache_update": 1}
+    with dispatch.capture() as c:
+        assert eng.step(q)[1]               # its own answer is homologous
+    assert c.counts() == {"speculate_batch": 1}
+    progs = (has._gather_rows, has._speculate_batch_impl,
+             has._cache_update_jit)
+    sizes = [p._cache_size() for p in progs]
+    accepts = [eng.step(x["emb"])[1] for x in qs[1:]]
+    assert len(accepts) == 20 and not all(accepts)
+    assert [p._cache_size() for p in progs] == sizes
 
 
 def test_serve_cli_prints_phases(capsys):

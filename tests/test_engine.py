@@ -1,8 +1,12 @@
 """Serving-engine integration: HaS vs baselines on a small world (fast)."""
+import dataclasses
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.has import HasConfig, cache_memory_bytes
+from repro.core.has import (HasConfig, HasState, cache_memory_bytes,
+                            cache_update, init_has_state, speculate_batch)
 from repro.data.synthetic import DATASETS, SyntheticWorld, WorldConfig
 from repro.serving.engine import (ANNSEngine, CRAGEngine, FullRetrievalEngine,
                                   HasEngine, ReuseEngine, RetrievalService)
@@ -27,6 +31,36 @@ def _has(service, **kw):
     cfg = HasConfig(k=10, tau=kw.pop("tau", 0.2), h_max=kw.pop("h_max", 800),
                     nprobe=8, n_buckets=128, d=service.world.cfg.d, **kw)
     return HasEngine(service, cfg)
+
+
+def test_step_matches_algorithm1_calls(service, queries):
+    """``HasEngine.step`` serves what ``speculate_batch``, the backend's
+    ``search`` and ``cache_update``, called directly in Algorithm 1's order
+    with eager device arrays, serve, and leaves the same cache bit for
+    bit."""
+    eng = _has(service, h_max=64)
+    cfg, state = eng.cfg, init_has_state(eng.cfg)
+    seen = set()
+    for q in queries[:80]:
+        ids, accept, _, homology = eng.step(q["emb"])
+        qd = jnp.asarray(q["emb"])
+        out = speculate_batch(cfg, state, eng.index, qd[None],
+                              backend=eng.backend)
+        want = bool(out["accept"][0])
+        if want:
+            want_ids = np.asarray(out["draft_ids"][0])
+        else:
+            full = service.backend.search(qd[None])[1][0]
+            want_ids = np.asarray(full)
+            state = cache_update(cfg, state, qd, full, service.corpus[full])
+        assert accept == want
+        assert homology == float(out["homology"][0])
+        np.testing.assert_array_equal(ids, want_ids)
+        seen.add(accept)
+    assert seen == {True, False}
+    for f in dataclasses.fields(HasState):
+        np.testing.assert_array_equal(np.asarray(getattr(eng.state, f.name)),
+                                      np.asarray(getattr(state, f.name)))
 
 
 def test_has_reduces_latency_vs_full(service, queries):
