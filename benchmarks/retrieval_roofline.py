@@ -68,10 +68,10 @@ def sweep_backends(out_path: str = "BENCH_speculate.json",
 
     Asserts the acceptance dispatch model: for B >= 32 the batch-native
     path issues <= 3 device dispatches per speculation batch (it issues
-    exactly 1), where the legacy per-query loop issues B.
+    exactly 1), where a per-query loop of B=1 calls issues B.
     """
     from repro.core import dispatch
-    from repro.core.has import (HasConfig, speculate, speculate_batch,
+    from repro.core.has import (HasConfig, speculate_batch,
                                 speculation_bytes_moved)
 
     rng = np.random.default_rng(0)
@@ -83,15 +83,16 @@ def sweep_backends(out_path: str = "BENCH_speculate.json",
     interpret = jax.default_backend() != "tpu"
     backends = ["xla", "pallas"]
 
-    # legacy per-query path: O(B) dispatches under host iteration —
-    # backend-independent, so measured once per batch size
+    # per-query path: B calls at B=1, O(B) dispatches under host
+    # iteration — backend-independent, so measured once per batch size
     legacy = {}
     for b in batches:
-        q = jnp.asarray(rng.normal(size=(b, cfg.d)), jnp.float32)
-        jax.block_until_ready(speculate(cfg, state, index, q[0]))  # compile
+        q = jnp.asarray(rng.normal(size=(b, 1, cfg.d)), jnp.float32)
+        jax.block_until_ready(speculate_batch(cfg, state, index, q[0]))
         with dispatch.capture() as legacy_probe:
             for i in range(b):
-                jax.block_until_ready(speculate(cfg, state, index, q[i]))
+                jax.block_until_ready(
+                    speculate_batch(cfg, state, index, q[i]))
         legacy[b] = legacy_probe.total()
 
     rows, records = [], []
@@ -174,17 +175,16 @@ def run():
                     f"vs_bare_llm_ttft~0.1s_x{full_t / 0.1:.1f}"))
 
     # HaS fast path budget: cache scan + validation at paper scale
-    from repro.core.has import HasConfig, init_has_state, speculate
+    from repro.core.has import HasConfig, init_has_state, speculate_batch
     from repro.retrieval.ivf import build_ivf
     cfg = HasConfig(k=10, tau=0.2, h_max=5000, nprobe=16, n_buckets=512,
                     d=64)
     state = init_has_state(cfg)
     index = build_ivf(svc.corpus[:50_000], 512, seed=0)
-    qv = q[0]
-    speculate(cfg, state, index, qv)  # compile
+    jax.block_until_ready(speculate_batch(cfg, state, index, q))  # compile
     t0 = time.perf_counter()
     for _ in range(10):
-        out = speculate(cfg, state, index, qv)
+        out = speculate_batch(cfg, state, index, q)
     jax.block_until_ready(out)
     t_spec = (time.perf_counter() - t0) / 10
     rows.append(row("roofline/has_fast_path", t_spec,

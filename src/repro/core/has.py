@@ -12,12 +12,12 @@ Entry points (each records itself on :mod:`repro.core.dispatch` so the
 serving layers' dispatch-count model is measurable, one record == one
 host→device program launch):
 
-``speculate``
-    One speculative retrieval for a single query [d]: two-channel top-k ->
-    rerank/merge -> draft -> homology validation (Algorithm 1 lines 1–14).
 ``speculate_batch``
-    The batch-native hot path: [B, d] queries through ONE jitted program
-    behind a ``backend="pallas" | "xla"`` switch.
+    Speculative retrieval of [B, d] queries (Algorithm 1 lines 1–14):
+    two-channel top-k -> rerank/merge -> draft -> homology validation, in
+    ONE jitted program behind a ``backend="pallas" | "xla"`` switch.  The
+    sequential engine calls it at B=1, the scheduler at its speculation
+    batch.
 
     * ``backend="xla"`` is the reference oracle (and the CPU default): a
       dense [B, Dc] cache-channel score matrix plus the jnp IVF search,
@@ -31,11 +31,7 @@ host→device program launch):
       per platform), numerically identical to the TPU path.
 
     Dedup-merge, rerank and validation are fused into the same jitted
-    program, so a batch of B queries costs exactly one device dispatch
-    instead of the O(B) launches of per-query serving.
-``speculate_batched``
-    Legacy ``vmap(speculate)`` lifting, kept as a second oracle for the
-    batch path (same numerics as ``backend="xla"``).
+    program, so a batch of B queries costs exactly one device dispatch.
 ``cache_update``
     Insert one fallback full-retrieval result (Algorithm 1 line 16).
 ``cache_update_batched``
@@ -74,7 +70,7 @@ byte-identical.
 
 The host-side serving loop (serving/engine.py) sequences these per query
 exactly as Algorithm 1; serving/batched.py and serving/scheduler.py drive
-the batch-native entry points.
+them a batch at a time.
 """
 from __future__ import annotations
 
@@ -88,9 +84,7 @@ import numpy as np
 
 from repro.core import dispatch
 from repro.core.homology import (homology_scores, homology_scores_batched,
-                                 homology_scores_weighted,
-                                 homology_scores_weighted_batched,
-                                 reidentify, rrf_draft_weights)
+                                 homology_scores_weighted, rrf_draft_weights)
 from repro.retrieval.ivf import IVFIndex, ivf_search
 
 
@@ -242,82 +236,51 @@ def _channel_merge(cfg: HasConfig):
     raise ValueError(f"unknown fusion mode {cfg.fusion!r}")
 
 
-def _speculate_impl(cfg: HasConfig, state: HasState, index: IVFIndex,
-                    q_emb: jax.Array):
-    q = q_emb[None, :]                                       # [1, d]
-
-    # cache channel: flat exact top-k over the doc store
-    sc = (q @ state.doc_emb.T)[0]                            # [Dc]
-    sc = jnp.where(state.doc_ids >= 0, sc, -jnp.inf)
-    s_c, slots = jax.lax.top_k(sc, cfg.k)
-    i_c = jnp.where(jnp.isfinite(s_c), state.doc_ids[slots], -1)
-
-    # fuzzy channel: aggressive IVF
-    s_f, i_f = ivf_search(index, q, nprobe=cfg.nprobe, k=cfg.k)
-    s_f, i_f = s_f[0], i_f[0]
-
-    # draft used for validation (V flag) and for output (E flag)
-    if cfg.fusion == "rrf":
-        def fuse(sa, ia, sb, ib):
-            return _rrf_merge(ia, ib, cfg.k, cfg.rrf_k)
-    else:
-        def fuse(sa, ia, sb, ib):
-            return _dedup_merge(sa, ia, sb, ib, cfg.k)
-    s_val, i_val = fuse(s_c, i_c, s_f, i_f) \
-        if cfg.use_fuzzy_validation else (s_c, i_c)
-    s_out, i_out = fuse(s_c, i_c, s_f, i_f) \
-        if cfg.use_fuzzy_enhancement else (s_c, i_c)
-
-    if cfg.fusion == "rrf":
-        # fused-list validation: rank-weighted homology mass, scale-free
-        s = homology_scores_weighted(
-            i_val, state.query_doc_ids, state.query_valid,
-            rrf_draft_weights(i_val, cfg.rrf_k))
-        slot = jnp.argmax(s).astype(jnp.int32)
-        best = s[slot]
-        accept = best > jnp.float32(cfg.tau)
-    else:
-        accept, best, slot = reidentify(
-            i_val, state.query_doc_ids, state.query_valid,
-            jnp.float32(cfg.tau))
-
-    return {"draft_ids": i_out, "draft_scores": s_out,
-            "val_ids": i_val, "accept": accept,
-            "homology": best, "matched_slot": slot}
-
-
-_speculate_jit = functools.partial(jax.jit, static_argnames=("cfg",))(
-    _speculate_impl)
-
-_speculate_batched_jit = jax.jit(
-    jax.vmap(_speculate_impl, in_axes=(None, None, None, 0)),
-    static_argnames=("cfg",))
-
-
-def speculate(cfg: HasConfig, state: HasState, index: IVFIndex,
-              q_emb: jax.Array):
-    """One speculative retrieval (Algorithm 1 lines 1–14) for query q [d].
-
-    Returns dict with draft ids/scores, accept flag, best homology score and
-    matched cache slot.
-    """
-    dispatch.record("speculate")
-    return _speculate_jit(cfg, state, index, q_emb)
-
-
-def speculate_batched(cfg: HasConfig, state: HasState, index: IVFIndex,
-                      q_embs: jax.Array):
-    """Legacy vmap lifting of :func:`speculate` over [B, d] queries."""
-    dispatch.record("speculate_batched")
-    return _speculate_batched_jit(cfg, state, index, q_embs)
+def _tenant_layout(state: HasState, tenant, name: str) -> None:
+    """Refuse a state whose layout does not match the tenant argument:
+    ``tenant`` (``tenant_ids`` or ``tenant_id``, called ``name``) is given
+    exactly when the state is a stacked :func:`init_tenant_states` store."""
+    if tenant is None:
+        if state.q_ptr.ndim != 0:
+            raise ValueError(
+                f"stacked tenant state requires {name} (or slice one "
+                "tenant out with tenant_slice)")
+    elif state.q_ptr.ndim != 1:
+        raise ValueError(f"{name} requires a stacked init_tenant_states "
+                         "state")
 
 
 @functools.partial(
     jax.jit, static_argnames=("cfg", "backend", "interpret", "tile_c"))
 def _speculate_batch_impl(cfg: HasConfig, state: HasState, index: IVFIndex,
-                          q_embs: jax.Array, backend: str, interpret: bool,
-                          tile_c: int):
+                          q_embs: jax.Array,
+                          tenant_ids: jax.Array | None = None, *,
+                          backend: str, interpret: bool, tile_c: int):
+    """Two-channel speculation and validation of [B, d] queries.
+
+    Without ``tenant_ids`` the state's arrays are read as they are.  With
+    ``tenant_ids [B]`` the state is a stacked ``[T, ...]`` store
+    (:func:`init_tenant_states`): the doc store and the query-cache table
+    flatten to ``[T*Dc]`` / ``[T*H]`` rows tagged with their tenant, and
+    scoring masks the rows of other tenants (group masks in the Pallas
+    kernels, a dense tenant-compare mask in the XLA oracle).  Which of the
+    two is decided while tracing, so each is a program of its own.  The
+    fuzzy channel is the corpus-derived IVF index, shared by construction
+    (it holds no per-tenant state).
+    """
     nprobe = min(cfg.nprobe, index.n_buckets)
+    doc_emb, doc_ids = state.doc_emb, state.doc_ids
+    qdi, qvalid = state.query_doc_ids, state.query_valid
+    doc_group = row_group = None
+    if tenant_ids is not None:
+        t, dc = doc_ids.shape
+        h = qvalid.shape[1]
+        doc_emb = doc_emb.reshape(t * dc, q_embs.shape[1])
+        doc_ids = doc_ids.reshape(t * dc)
+        qdi = qdi.reshape(t * h, cfg.k)
+        qvalid = qvalid.reshape(t * h)
+        doc_group = jnp.repeat(jnp.arange(t, dtype=jnp.int32), dc)
+        row_group = jnp.repeat(jnp.arange(t, dtype=jnp.int32), h)
 
     if backend == "pallas":
         from repro.kernels.homology_score import homology_score
@@ -325,11 +288,11 @@ def _speculate_batch_impl(cfg: HasConfig, state: HasState, index: IVFIndex,
         from repro.kernels.topk_search import topk_search
 
         # cache channel: streaming tiled top-k, doc store stays in VMEM
-        s_c, slots = topk_search(q_embs, state.doc_emb, cfg.k,
-                                 tile_c=tile_c, valid=state.doc_ids >= 0,
-                                 interpret=interpret)
+        s_c, slots = topk_search(q_embs, doc_emb, cfg.k, tile_c=tile_c,
+                                 valid=doc_ids >= 0, row_group=doc_group,
+                                 q_group=tenant_ids, interpret=interpret)
         i_c = jnp.where(jnp.isfinite(s_c),
-                        state.doc_ids[jnp.maximum(slots, 0)], -1)
+                        doc_ids[jnp.maximum(slots, 0)], -1)
 
         # fuzzy channel: centroid top-nprobe on the MXU, then the
         # scalar-prefetch bucket scan (no [B, nprobe, cap, d] gather)
@@ -340,86 +303,10 @@ def _speculate_batch_impl(cfg: HasConfig, state: HasState, index: IVFIndex,
                             interpret=interpret)
     elif backend == "xla":
         # reference oracle: dense score matrix + materialized bucket gather
-        sc = q_embs @ state.doc_emb.T                        # [B, Dc]
-        sc = jnp.where(state.doc_ids[None, :] >= 0, sc, -jnp.inf)
-        s_c, slots = jax.lax.top_k(sc, cfg.k)
-        i_c = jnp.where(jnp.isfinite(s_c), state.doc_ids[slots], -1)
-        s_f, i_f = ivf_search(index, q_embs, nprobe=cfg.nprobe, k=cfg.k)
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
-
-    merge = _channel_merge(cfg)
-    s_val, i_val = merge(s_c, i_c, s_f, i_f) \
-        if cfg.use_fuzzy_validation else (s_c, i_c)
-    s_out, i_out = merge(s_c, i_c, s_f, i_f) \
-        if cfg.use_fuzzy_enhancement else (s_c, i_c)
-
-    w_val = rrf_draft_weights(i_val, cfg.rrf_k) \
-        if cfg.fusion == "rrf" else None
-    if backend == "pallas":
-        scores = homology_score(i_val, state.query_doc_ids,
-                                state.query_valid, draft_weights=w_val,
-                                interpret=interpret)
-    elif w_val is not None:
-        scores = homology_scores_weighted_batched(
-            i_val, state.query_doc_ids, state.query_valid, w_val)
-    else:
-        scores = homology_scores_batched(i_val, state.query_doc_ids,
-                                         state.query_valid)
-    slot = jnp.argmax(scores, axis=1).astype(jnp.int32)      # [B]
-    best = jnp.take_along_axis(scores, slot[:, None], axis=1)[:, 0]
-    accept = best > jnp.float32(cfg.tau)
-
-    return {"draft_ids": i_out, "draft_scores": s_out,
-            "val_ids": i_val, "accept": accept,
-            "homology": best, "matched_slot": slot}
-
-
-@functools.partial(
-    jax.jit, static_argnames=("cfg", "backend", "interpret", "tile_c"))
-def _speculate_batch_tenant_impl(cfg: HasConfig, state: HasState,
-                                 index: IVFIndex, q_embs: jax.Array,
-                                 tenant_ids: jax.Array, backend: str,
-                                 interpret: bool, tile_c: int):
-    """Tenant-partitioned speculation: one program, per-query cache slices.
-
-    ``state`` is a stacked ``[T, ...]`` store (:func:`init_tenant_states`);
-    ``tenant_ids [B]`` selects each query's partition.  Both channels that
-    hold tenant data — the doc-store cache channel and the query-cache
-    validation table — flatten to ``[T*Dc]`` / ``[T*H]`` rows tagged with
-    their tenant, and the scoring masks rows whose tenant differs from the
-    query's (per-query group masking in the Pallas kernels; a dense
-    tenant-compare mask in the XLA oracle).  The fuzzy channel is the
-    corpus-derived IVF index, shared by construction (it holds no
-    per-tenant state).  T == 1 is bit-exact with the unpartitioned path.
-    """
-    t, dc = state.doc_ids.shape
-    h = state.query_valid.shape[1]
-    d = q_embs.shape[1]
-    nprobe = min(cfg.nprobe, index.n_buckets)
-    doc_emb = state.doc_emb.reshape(t * dc, d)
-    doc_ids = state.doc_ids.reshape(t * dc)
-    doc_tenant = jnp.repeat(jnp.arange(t, dtype=jnp.int32), dc)
-
-    if backend == "pallas":
-        from repro.kernels.homology_score import homology_score
-        from repro.kernels.ivf_scan import ivf_scan
-        from repro.kernels.topk_search import topk_search
-
-        s_c, slots = topk_search(q_embs, doc_emb, cfg.k, tile_c=tile_c,
-                                 valid=doc_ids >= 0, row_group=doc_tenant,
-                                 q_group=tenant_ids, interpret=interpret)
-        i_c = jnp.where(jnp.isfinite(s_c),
-                        doc_ids[jnp.maximum(slots, 0)], -1)
-        cscores = q_embs @ index.centroids.T                 # [B, C]
-        _, probe = jax.lax.top_k(cscores, nprobe)
-        s_f, i_f = ivf_scan(q_embs, probe.astype(jnp.int32),
-                            index.bucket_vecs, index.bucket_ids, cfg.k,
-                            interpret=interpret)
-    elif backend == "xla":
-        sc = q_embs @ doc_emb.T                              # [B, T*Dc]
-        ok = (doc_ids[None, :] >= 0) \
-            & (doc_tenant[None, :] == tenant_ids[:, None])
+        sc = q_embs @ doc_emb.T                              # [B, Dc]
+        ok = doc_ids[None, :] >= 0
+        if tenant_ids is not None:
+            ok = ok & (doc_group[None, :] == tenant_ids[:, None])
         sc = jnp.where(ok, sc, -jnp.inf)
         s_c, slots = jax.lax.top_k(sc, cfg.k)
         i_c = jnp.where(jnp.isfinite(s_c), doc_ids[slots], -1)
@@ -433,26 +320,27 @@ def _speculate_batch_tenant_impl(cfg: HasConfig, state: HasState,
     s_out, i_out = merge(s_c, i_c, s_f, i_f) \
         if cfg.use_fuzzy_enhancement else (s_c, i_c)
 
-    qdi = state.query_doc_ids.reshape(t * h, cfg.k)
-    qvalid = state.query_valid.reshape(t * h)
-    row_tenant = jnp.repeat(jnp.arange(t, dtype=jnp.int32), h)
     w_val = rrf_draft_weights(i_val, cfg.rrf_k) \
         if cfg.fusion == "rrf" else None
     if backend == "pallas":
-        scores = homology_score(i_val, qdi, qvalid, row_group=row_tenant,
+        scores = homology_score(i_val, qdi, qvalid, row_group=row_group,
                                 q_group=tenant_ids, draft_weights=w_val,
                                 interpret=interpret)
     else:
-        valid_b = qvalid[None, :] \
-            & (row_tenant[None, :] == tenant_ids[:, None])   # [B, T*H]
+        valid, axis = qvalid, None
+        if tenant_ids is not None:
+            valid = qvalid[None, :] \
+                & (row_group[None, :] == tenant_ids[:, None])  # [B, T*H]
+            axis = 0
         if w_val is not None:
-            scores = jax.vmap(
-                homology_scores_weighted, in_axes=(0, None, 0, 0))(
-                i_val, qdi, valid_b, w_val)
+            scores = jax.vmap(homology_scores_weighted,
+                              in_axes=(0, None, axis, 0))(
+                i_val, qdi, valid, w_val)
         else:
-            scores = jax.vmap(homology_scores, in_axes=(0, None, 0))(
-                i_val, qdi, valid_b)
-    # matched_slot is flat over [T*H]: tenant t's slot s is t*h_max + s
+            scores = jax.vmap(homology_scores, in_axes=(0, None, axis))(
+                i_val, qdi, valid)
+    # with tenants, matched_slot is flat over [T*H]: slot s of tenant t is
+    # t*h_max + s
     slot = jnp.argmax(scores, axis=1).astype(jnp.int32)      # [B]
     best = jnp.take_along_axis(scores, slot[:, None], axis=1)[:, 0]
     accept = best > jnp.float32(cfg.tau)
@@ -466,12 +354,14 @@ def speculate_batch(cfg: HasConfig, state: HasState, index: IVFIndex,
                     q_embs: jax.Array, backend: str | None = None,
                     interpret: bool | None = None, tile_c: int = 1024,
                     tenant_ids: jax.Array | None = None):
-    """Batch-native speculation: [B, d] queries, one device dispatch.
+    """Speculative retrieval (Algorithm 1 lines 1–14) of [B, d] queries in
+    one device dispatch.
 
     ``backend=None`` auto-selects (:func:`default_backend`): the Pallas
     kernel pipeline on TPU, the XLA reference on CPU.  ``interpret=None``
-    runs the kernels in interpret mode off-TPU.  Returns the same dict as
-    :func:`speculate` with a leading batch axis on every entry.
+    runs the kernels in interpret mode off-TPU.  Returns a dict of [B, ...]
+    arrays: the draft ids/scores, the validation draft ``val_ids``, the
+    accept flag, the best homology score and the matched cache slot.
 
     ``tenant_ids [B]`` (optional) routes each query through its tenant's
     partition of a stacked :func:`init_tenant_states` store — still one
@@ -485,20 +375,12 @@ def speculate_batch(cfg: HasConfig, state: HasState, index: IVFIndex,
     elif interpret is None:
         interpret = jax.default_backend() != "tpu"
     dispatch.record("speculate_batch")
-    if tenant_ids is None:
-        if state.q_ptr.ndim != 0:
-            raise ValueError(
-                "stacked tenant state requires tenant_ids (or slice one "
-                "tenant out with tenant_slice)")
-        return _speculate_batch_impl(cfg, state, index, q_embs,
-                                     backend=backend, interpret=interpret,
-                                     tile_c=tile_c)
-    if state.q_ptr.ndim != 1:
-        raise ValueError("tenant_ids requires a stacked init_tenant_states "
-                         "state")
-    return _speculate_batch_tenant_impl(
-        cfg, state, index, q_embs, jnp.asarray(tenant_ids, jnp.int32),
-        backend=backend, interpret=interpret, tile_c=tile_c)
+    _tenant_layout(state, tenant_ids, "tenant_ids")
+    if tenant_ids is not None:
+        tenant_ids = jnp.asarray(tenant_ids, jnp.int32)
+    return _speculate_batch_impl(cfg, state, index, q_embs, tenant_ids,
+                                 backend=backend, interpret=interpret,
+                                 tile_c=tile_c)
 
 
 # ---------------------------------------------------------------------------
@@ -575,7 +457,15 @@ def intra_batch_share(val_ids: jax.Array, rejected: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _cache_update_impl(cfg: HasConfig, state: HasState, q_emb: jax.Array,
-                       full_ids: jax.Array, full_vecs: jax.Array) -> HasState:
+                       full_ids: jax.Array, full_vecs: jax.Array,
+                       tenant_id: jax.Array | None = None) -> HasState:
+    if tenant_id is not None:
+        # one tenant of a stacked store (decided while tracing): gather its
+        # slice, update it, scatter it back; the id may be traced
+        sl = _cache_update_impl(cfg, tenant_slice(state, tenant_id), q_emb,
+                                full_ids, full_vecs)
+        return jax.tree_util.tree_map(lambda a, b: a.at[tenant_id].set(b),
+                                      state, sl)
     h = cfg.h_max
     slot = state.q_ptr % h
     query_emb = state.query_emb.at[slot].set(q_emb)
@@ -609,20 +499,6 @@ _cache_update_jit = functools.partial(
         _cache_update_impl)
 
 
-def _tenant_update(cfg: HasConfig, state: HasState, t, q_emb, full_ids,
-                   full_vecs) -> HasState:
-    """Apply one ``_cache_update_impl`` to tenant t's slice of a stacked
-    store (gather slice -> update -> scatter back; t may be traced)."""
-    sl = jax.tree_util.tree_map(lambda a: a[t], state)
-    sl = _cache_update_impl(cfg, sl, q_emb, full_ids, full_vecs)
-    return jax.tree_util.tree_map(lambda a, b: a.at[t].set(b), state, sl)
-
-
-_cache_update_tenant_jit = functools.partial(
-    jax.jit, static_argnames=("cfg",), donate_argnames=("state",))(
-        _tenant_update)
-
-
 def cache_update(cfg: HasConfig, state: HasState, q_emb: jax.Array,
                  full_ids: jax.Array, full_vecs: jax.Array,
                  tenant_id=None) -> HasState:
@@ -632,50 +508,28 @@ def cache_update(cfg: HasConfig, state: HasState, q_emb: jax.Array,
     :func:`init_tenant_states` store; all other partitions are untouched.
     """
     dispatch.record("cache_update")
-    if tenant_id is None:
-        if state.q_ptr.ndim != 0:
+    _tenant_layout(state, tenant_id, "tenant_id")
+    if tenant_id is not None:
+        if not 0 <= int(tenant_id) < state.q_ptr.shape[0]:
             raise ValueError(
-                "stacked tenant state requires tenant_id (or slice one "
-                "tenant out with tenant_slice)")
-        return _cache_update_jit(cfg, state, q_emb, full_ids, full_vecs)
-    if state.q_ptr.ndim != 1:
-        raise ValueError("tenant_id requires a stacked init_tenant_states "
-                         "state")
-    if not 0 <= int(tenant_id) < state.q_ptr.shape[0]:
-        raise ValueError(
-            f"tenant_id {int(tenant_id)} out of range for "
-            f"{state.q_ptr.shape[0]} tenants")
-    return _cache_update_tenant_jit(cfg, state, jnp.int32(tenant_id),
-                                    q_emb, full_ids, full_vecs)
+                f"tenant_id {int(tenant_id)} out of range for "
+                f"{state.q_ptr.shape[0]} tenants")
+        tenant_id = jnp.int32(tenant_id)
+    return _cache_update_jit(cfg, state, q_emb, full_ids, full_vecs,
+                             tenant_id)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("state",))
 def _cache_update_batched_jit(cfg: HasConfig, state: HasState,
                               q_embs: jax.Array, full_ids: jax.Array,
-                              full_vecs: jax.Array,
-                              mask: jax.Array) -> HasState:
-    def body(st, xs):
-        q, ids, vecs, on = xs
-        st = jax.lax.cond(
-            on, lambda s: _cache_update_impl(cfg, s, q, ids, vecs),
-            lambda s: s, st)
-        return st, None
-
-    state, _ = jax.lax.scan(body, state, (q_embs, full_ids, full_vecs, mask))
-    return state
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",),
-                   donate_argnames=("state",))
-def _cache_update_batched_tenant_jit(cfg: HasConfig, state: HasState,
-                                     q_embs: jax.Array, full_ids: jax.Array,
-                                     full_vecs: jax.Array, mask: jax.Array,
-                                     tenant_ids: jax.Array) -> HasState:
+                              full_vecs: jax.Array, mask: jax.Array,
+                              tenant_ids: jax.Array | None = None
+                              ) -> HasState:
     def body(st, xs):
         q, ids, vecs, on, t = xs
         st = jax.lax.cond(
-            on, lambda s: _tenant_update(cfg, s, t, q, ids, vecs),
+            on, lambda s: _cache_update_impl(cfg, s, q, ids, vecs, t),
             lambda s: s, st)
         return st, None
 
@@ -705,19 +559,11 @@ def cache_update_batched(cfg: HasConfig, state: HasState, q_embs: jax.Array,
     if mask is None:
         mask = jnp.ones((q_embs.shape[0],), bool)
     dispatch.record("cache_update_batched")
-    if tenant_ids is None:
-        if state.q_ptr.ndim != 0:
-            raise ValueError(
-                "stacked tenant state requires tenant_ids (or slice one "
-                "tenant out with tenant_slice)")
-        return _cache_update_batched_jit(cfg, state, q_embs, full_ids,
-                                         full_vecs, mask)
-    if state.q_ptr.ndim != 1:
-        raise ValueError("tenant_ids requires a stacked init_tenant_states "
-                         "state")
-    return _cache_update_batched_tenant_jit(
-        cfg, state, q_embs, full_ids, full_vecs, mask,
-        jnp.asarray(tenant_ids, jnp.int32))
+    _tenant_layout(state, tenant_ids, "tenant_ids")
+    if tenant_ids is not None:
+        tenant_ids = jnp.asarray(tenant_ids, jnp.int32)
+    return _cache_update_batched_jit(cfg, state, q_embs, full_ids,
+                                     full_vecs, mask, tenant_ids)
 
 
 def cache_update_chunked(cfg: HasConfig, state: HasState, q_embs, full_ids,

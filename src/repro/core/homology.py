@@ -75,15 +75,6 @@ def homology_scores_weighted(draft_ids: jax.Array, cache_doc_ids: jax.Array,
     return jnp.where(cache_valid, s, 0.0)
 
 
-def homology_scores_weighted_batched(draft_ids: jax.Array,
-                                     cache_doc_ids: jax.Array,
-                                     cache_valid: jax.Array,
-                                     draft_weights: jax.Array) -> jax.Array:
-    """draft_ids/draft_weights [B, k] -> scores [B, H]."""
-    return jax.vmap(lambda d, w: homology_scores_weighted(
-        d, cache_doc_ids, cache_valid, w))(draft_ids, draft_weights)
-
-
 @functools.partial(jax.jit, static_argnames=())
 def reidentify(draft_ids: jax.Array, cache_doc_ids: jax.Array,
                cache_valid: jax.Array, tau: jax.Array):

@@ -71,7 +71,8 @@ def test_cache_ring_never_exceeds_capacity(seed, n_inserts):
 def test_speculate_draft_ids_come_from_channels(seed):
     """Property: every returned draft id is a live cached doc or an IVF-
     indexed corpus id (never fabricated)."""
-    from repro.core.has import HasConfig, cache_update, init_has_state, speculate
+    from repro.core.has import (HasConfig, cache_update, init_has_state,
+                                speculate_batch)
     from repro.retrieval.ivf import build_ivf
     rng = np.random.default_rng(seed)
     cfg = HasConfig(k=4, tau=0.3, h_max=8, doc_capacity=32, nprobe=2,
@@ -81,9 +82,9 @@ def test_speculate_draft_ids_come_from_channels(seed):
     state = init_has_state(cfg)
     ids0 = jnp.asarray(rng.integers(0, 64, 4), jnp.int32)
     state = cache_update(cfg, state, jnp.ones((8,)), ids0, corpus[ids0])
-    q = jnp.asarray(rng.normal(size=(8,)), jnp.float32)
-    out = speculate(cfg, state, index, q)
+    q = jnp.asarray(rng.normal(size=(1, 8)), jnp.float32)
+    out = speculate_batch(cfg, state, index, q)
     live = set(np.asarray(state.doc_ids)[np.asarray(state.doc_ids) >= 0])
     indexed = set(np.asarray(index.bucket_ids).reshape(-1))
-    for d in np.asarray(out["draft_ids"]):
+    for d in np.asarray(out["draft_ids"])[0]:
         assert d == -1 or int(d) in (live | indexed)

@@ -8,7 +8,8 @@ import pytest
 # real hypothesis when installed, skip-stubs otherwise (see conftest.py)
 from conftest import given, settings, st
 
-from repro.core.has import HasConfig, cache_update, init_has_state, speculate
+from repro.core.has import (HasConfig, cache_update, init_has_state,
+                            speculate_batch)
 from repro.core.homology import (homology_scores, pairwise_homology,
                                  reidentify)
 from repro.core.reference import RefHas
@@ -81,8 +82,10 @@ def test_invalid_slots_score_zero():
     assert float(s[0]) == 0.0 and float(s[1]) == 1.0
 
 
-def test_algorithm1_equivalence_with_reference():
-    """Jitted fixed-shape HaS == faithful hash-map reference, per query."""
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_algorithm1_equivalence_with_reference(backend):
+    """Jitted fixed-shape HaS == faithful hash-map reference, per query,
+    through the B=1 speculation program the sequential engine runs."""
     k, h_max, doc_cap, d = 5, 16, 128, 16
     cfg = HasConfig(k=k, tau=0.3, h_max=h_max, doc_capacity=doc_cap,
                     nprobe=2, n_buckets=4, d=d,
@@ -100,11 +103,13 @@ def test_algorithm1_equivalence_with_reference():
     for step in range(60):
         q = rng.normal(size=(d,)).astype(np.float32)
         q /= np.linalg.norm(q)
-        out = speculate(cfg, state, index, jnp.asarray(q))
+        out = speculate_batch(cfg, state, index, q[None], backend=backend,
+                              interpret=True)
+        out = {key: np.asarray(v)[0] for key, v in out.items()}
         # reference: cache-channel draft + inverted-index validation
         ref_ids, _ = refi.cache_channel(q)
         accept_ref, _ = refi.validate(ref_ids)
-        got_ids = np.asarray(out["val_ids"])
+        got_ids = out["val_ids"]
         live_got = sorted(int(i) for i in got_ids if i >= 0)
         live_ref = sorted(int(i) for i in ref_ids if i >= 0)
         assert live_got == live_ref, (step, live_got, live_ref)
@@ -131,12 +136,12 @@ def test_fuzzy_ablation_flags():
     # insert one query so the cache channel is non-empty
     state = cache_update(cfg_full, state, jnp.ones((8,)),
                          jnp.asarray([0, 1, 2, 3], jnp.int32), corpus[:4])
-    q = jnp.asarray(rng.normal(size=(8,)), jnp.float32)
-    out_full = speculate(cfg_full, state, index, q)
-    out_noE = speculate(cfg_noE, state, index, q)
+    q = jnp.asarray(rng.normal(size=(1, 8)), jnp.float32)
+    out_full = speculate_batch(cfg_full, state, index, q)
+    out_noE = speculate_batch(cfg_noE, state, index, q)
     # without enhancement the returned draft only contains cached docs
     cached = {0, 1, 2, 3, -1}
-    assert set(np.asarray(out_noE["draft_ids"]).tolist()) <= cached
+    assert set(np.asarray(out_noE["draft_ids"])[0].tolist()) <= cached
     # validation drafts identical (V on in both)
     assert np.array_equal(np.asarray(out_full["val_ids"]),
                           np.asarray(out_noE["val_ids"]))

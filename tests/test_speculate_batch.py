@@ -16,8 +16,8 @@ import pytest
 
 from repro.core import dispatch
 from repro.core.has import (HasConfig, _dedup_merge, cache_update,
-                            cache_update_batched, init_has_state, speculate,
-                            speculate_batch, speculate_batched)
+                            cache_update_batched, init_has_state,
+                            speculate_batch)
 from repro.retrieval.ivf import build_ivf
 
 RNG = np.random.default_rng(11)
@@ -115,28 +115,25 @@ def test_backend_parity_tail_tile():
     _assert_outputs_equal(out_x, out_p)
 
 
-def test_xla_backend_matches_vmap_oracle():
-    """The batch-first XLA program == the legacy vmap(speculate) lifting."""
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_single_query_consistency(backend):
+    """A query alone at B=1 (the sequential engine's program) gets the
+    same outputs as the same query as one row of a B=8 batch (the
+    scheduler's)."""
     cfg = HasConfig(k=5, tau=0.2, h_max=32, doc_capacity=96, nprobe=2,
                     n_buckets=8, d=16)
     corpus, index, state = _world(cfg)
-    q = jnp.asarray(RNG.normal(size=(6, cfg.d)), jnp.float32)
-    out_x = speculate_batch(cfg, state, index, q, backend="xla")
-    out_v = speculate_batched(cfg, state, index, q)
-    _assert_outputs_equal(out_x, out_v)
-
-
-def test_single_query_consistency():
-    """speculate_batch on a batch of one == the sequential speculate."""
-    cfg = HasConfig(k=5, tau=0.2, h_max=32, doc_capacity=96, nprobe=2,
-                    n_buckets=8, d=16)
-    corpus, index, state = _world(cfg)
-    q = jnp.asarray(RNG.normal(size=(cfg.d,)), jnp.float32)
-    out_b = speculate_batch(cfg, state, index, q[None], backend="xla")
-    out_s = speculate(cfg, state, index, q)
-    for key in ("accept", "homology", "val_ids", "draft_ids"):
-        np.testing.assert_array_equal(np.asarray(out_b[key])[0],
-                                      np.asarray(out_s[key]), err_msg=key)
+    docs = np.asarray(state.doc_emb)[np.asarray(state.doc_ids) >= 0]
+    q = np.concatenate([docs[:4] + 0.01 * RNG.normal(size=(4, cfg.d)),
+                        RNG.normal(size=(4, cfg.d))]).astype(np.float32)
+    kw = dict(backend=backend, interpret=True, tile_c=64)
+    out_b = speculate_batch(cfg, state, index, jnp.asarray(q), **kw)
+    assert np.asarray(out_b["accept"]).any()
+    for i in range(len(q)):
+        out_1 = speculate_batch(cfg, state, index, jnp.asarray(q[i:i + 1]),
+                                **kw)
+        _assert_outputs_equal({key: np.asarray(v)[i:i + 1]
+                               for key, v in out_b.items()}, out_1)
 
 
 # -- fused ingest ----------------------------------------------------------
@@ -221,10 +218,6 @@ def test_batch_entry_points_are_single_dispatch():
             jnp.zeros((4, cfg.k), jnp.int32), jnp.zeros((4, cfg.k, cfg.d)),
             jnp.zeros((4,), bool))
     assert probe.counts() == {"cache_update_batched": 1}
-    with dispatch.capture() as probe:
-        for i in range(4):
-            speculate(cfg, state, index, q[i])   # legacy: O(B) dispatches
-    assert probe.counts() == {"speculate": 4}
 
 
 # -- benchmark smoke (slow: exercises the full sweep machinery) ------------

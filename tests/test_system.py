@@ -1,12 +1,11 @@
 """End-to-end system tests: dry-run lowering (subprocess), graph sampler,
-LM training convergence, batched speculation."""
+LM training convergence, the has-rag dry-run step."""
 import json
 import os
 import subprocess
 import sys
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -62,27 +61,6 @@ def test_lm_training_loss_decreases():
     losses = train_lm(cfg, steps=30, batch=8, seq=32, ckpt_dir=None,
                       log_every=1000)
     assert np.mean(losses[-5:]) < np.mean(losses[:5]) - 0.2
-
-
-def test_speculate_batched_matches_single():
-    from repro.core.has import (HasConfig, cache_update, init_has_state,
-                                speculate, speculate_batched)
-    from repro.retrieval.ivf import build_ivf
-    rng = np.random.default_rng(0)
-    cfg = HasConfig(k=4, tau=0.2, h_max=16, doc_capacity=64, nprobe=2,
-                    n_buckets=4, d=8)
-    corpus = jnp.asarray(rng.normal(size=(128, 8)), jnp.float32)
-    index = build_ivf(corpus, 4, seed=0)
-    state = init_has_state(cfg)
-    state = cache_update(cfg, state, jnp.ones((8,)),
-                         jnp.asarray([0, 1, 2, 3], jnp.int32), corpus[:4])
-    qs = jnp.asarray(rng.normal(size=(6, 8)), jnp.float32)
-    batched = speculate_batched(cfg, state, index, qs)
-    for i in range(6):
-        single = speculate(cfg, state, index, qs[i])
-        for key in ("draft_ids", "accept", "homology"):
-            np.testing.assert_array_equal(np.asarray(batched[key][i]),
-                                          np.asarray(single[key]), err_msg=key)
 
 
 def test_has_dryrun_step_semantics():

@@ -20,8 +20,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.has import (HasConfig, HasState, _speculate_batch_impl,
-                            _speculate_batch_tenant_impl)
+from repro.core.has import HasConfig, HasState, _speculate_batch_impl
 from repro.kernels.fused_rerank import fused_rerank
 from repro.kernels.homology_score import homology_score
 from repro.kernels.ivf_scan import bucket_format, ivf_scan
@@ -182,17 +181,15 @@ def _state(shape, tenants=None):
 
 
 def _speculation(shape, index, tenants):
-    """The Pallas speculation program over ``index``, compiled."""
-    q = shape((B, D), jnp.float32)
-    common = dict(backend="pallas", interpret=False, tile_c=1024)
-    if tenants is None:
-        lowered = jax.jit(lambda st, ix, q: _speculate_batch_impl(
-            CFG, st, ix, q, **common)).lower(_state(shape), index, q)
-    else:
-        lowered = jax.jit(lambda st, ix, q, t: _speculate_batch_tenant_impl(
-            CFG, st, ix, q, t, **common)).lower(
-                _state(shape, tenants), index, q, shape((B,), jnp.int32))
-    return lowered.compile()
+    """The Pallas speculation program over ``index``, compiled: the
+    single-tenant program, or with ``tenants`` the one over a stacked
+    store."""
+    args = [_state(shape, tenants), index, shape((B, D), jnp.float32)]
+    if tenants is not None:
+        args.append(shape((B,), jnp.int32))
+    return _speculate_batch_impl.lower(
+        CFG, *args, backend="pallas", interpret=False,
+        tile_c=1024).compile()
 
 
 @pytest.mark.parametrize("tenants", [None, 2], ids=["single", "tenants"])
