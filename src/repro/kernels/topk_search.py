@@ -6,6 +6,11 @@ selection, streamed over corpus tiles so the score matrix never leaves VMEM.
 TPU mapping:
   * grid = corpus tiles; each step loads a [TILE_C, d] corpus block into
     VMEM and issues one [B, d] x [d, TILE_C] MXU matmul.
+  * the corpus streams as it is stored: when N is no whole number of
+    tiles, the last tile is a partial block read in place (no padded copy
+    of the [N, d] array).  Its rows past N hold unspecified values (NaN
+    included), never assumed to be zero: the kernel turns their scores
+    into -inf with a select before any comparison.
   * the running top-k (vals/idx [B, K]) lives in the revisited output block
     (same index_map every step => stays resident in VMEM).
   * merge = K rounds of (tile argmax -> replace running argmin) — O(K·TILE)
@@ -44,8 +49,9 @@ def _topk_kernel(q_ref, c_ref, valid_ref, *rest, k: int, tile_c: int,
         preferred_element_type=jnp.float32)               # [B, TILE_C]
     base = step * tile_c
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    # mask the tail tile's out-of-range columns and invalid corpus rows
-    # (empty doc-store ring slots when scanning a HaS cache channel)
+    # mask the partial last tile's rows past N (unspecified values, so a
+    # select and never a multiply) and invalid corpus rows (empty doc-store
+    # ring slots when scanning a HaS cache channel)
     ok = (base + col < n_corpus) & valid[None, :]
     if grouped:
         # partitioned scan: row i may only win for queries of its group
@@ -92,6 +98,11 @@ def topk_search(queries: jax.Array, corpus: jax.Array, k: int,
     launch but rows never cross tenants.  The group ids stream with the
     corpus tiles, so the partitioned scan stays one program launch with one
     extra [B, TILE_C] compare per tile.
+
+    The grid is ``cdiv(N, tile_c)`` tiles of the arrays as passed: ``corpus``,
+    ``valid`` and ``row_group`` are never padded to whole tiles.  The last
+    tile is a partial block read in place, and the kernel masks its rows
+    past N whatever they hold.
     """
     n, d = corpus.shape
     b = queries.shape[0]
@@ -101,14 +112,6 @@ def topk_search(queries: jax.Array, corpus: jax.Array, k: int,
     if valid is None:
         valid = jnp.ones((n,), bool)
     n_tiles = pl.cdiv(n, tile_c)
-    pad = n_tiles * tile_c - n
-    if pad:
-        corpus = jnp.concatenate(
-            [corpus, jnp.zeros((pad, d), corpus.dtype)], axis=0)
-        valid = jnp.concatenate([valid, jnp.zeros((pad,), bool)])
-        if grouped:
-            row_group = jnp.concatenate(
-                [row_group, jnp.full((pad,), -1, jnp.int32)])
 
     in_specs = [
         pl.BlockSpec((b, d), lambda i: (0, 0)),        # queries resident

@@ -5,29 +5,81 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.topk_search import topk_search
 
 RNG = np.random.default_rng(7)
 
 
-@pytest.mark.parametrize("b,n,d,k,tile", [
-    (1, 257, 32, 5, 64),
-    (4, 1024, 64, 10, 256),
-    (8, 5000, 128, 16, 512),
-    (2, 100, 16, 10, 128),     # corpus smaller than tile
-    (3, 4096, 64, 64, 1024),   # large k
+def _topk_case(b, n, d, k, tile, plant=None):
+    """A ``test_topk_search`` case; a ``plant`` case appends its name."""
+    name = "-".join(map(str, (b, n, d, k, tile)))
+    return pytest.param(b, n, d, k, tile, plant,
+                        id=name if plant is None else f"{name}-{plant}")
+
+
+@pytest.mark.parametrize("b,n,d,k,tile,plant", [
+    _topk_case(1, 257, 32, 5, 64),
+    _topk_case(4, 1024, 64, 10, 256),
+    _topk_case(8, 5000, 128, 16, 512),
+    _topk_case(2, 100, 16, 10, 128),     # corpus smaller than tile
+    _topk_case(3, 4096, 64, 64, 1024),   # large k
+    # ragged corpora (N = m*tile + r, r = 1 or tile - 1) whose rows all
+    # score below 0, the r rows of the partial last block highest: a row
+    # past N that escaped the mask (zero or garbage) would win a top-k
+    # position, or push one of those r rows out
+    _topk_case(2, 3 * 64 + 1, 32, 10, 64, "negative"),
+    _topk_case(3, 2 * 64 + 63, 32, 10, 64, "negative"),
+    # ... with ``valid`` hiding the rows that would win
+    _topk_case(2, 3 * 64 + 1, 32, 10, 64, "valid"),
+    _topk_case(3, 2 * 128 + 127, 32, 10, 128, "valid"),
+    # ... and partitioned by row_group / q_group
+    _topk_case(4, 2 * 128 + 1, 32, 5, 128, "grouped"),
+    _topk_case(4, 128 + 127, 32, 5, 128, "grouped"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_topk_search(b, n, d, k, tile, dtype):
-    q = jnp.asarray(RNG.normal(size=(b, d)), dtype)
-    c = jnp.asarray(RNG.normal(size=(n, d)), dtype)
-    v, i = ops.topk_search(q, c, k, tile_c=tile, interpret=True)
-    vr, ir = ref.topk_search_ref(q, c, k)
+def test_topk_search(b, n, d, k, tile, plant, dtype):
+    if plant is None:
+        q = jnp.asarray(RNG.normal(size=(b, d)), dtype)
+        c = jnp.asarray(RNG.normal(size=(n, d)), dtype)
+    else:
+        q = jnp.asarray(np.abs(RNG.normal(size=(b, d))) + 0.1, dtype)
+        c = -np.abs(RNG.normal(size=(n, d))) - 0.1
+        c[n // tile * tile:] *= 0.1
+        c = jnp.asarray(c, dtype)
+    ok = np.ones((b, n), bool)          # row i may win for query j
+    kw, search = {}, ops.topk_search
+    if plant == "valid":
+        _, top = ref.topk_search_ref(q, c, k // 2)
+        valid = np.ones(n, bool)
+        valid[np.asarray(top).ravel()] = False
+        ok &= valid[None, :]
+        kw["valid"] = jnp.asarray(valid)
+    elif plant == "grouped":
+        row_group = RNG.integers(0, 3, n).astype(np.int32)
+        q_group = RNG.integers(0, 3, b).astype(np.int32)
+        ok &= row_group[None, :] == q_group[:, None]
+        kw.update(row_group=jnp.asarray(row_group),
+                  q_group=jnp.asarray(q_group))
+        search = topk_search            # the ops wrapper passes no groups
+    v, i = search(q, c, k, tile_c=tile, interpret=True, **kw)
+    # the oracle, query by query over the rows that query may see
+    vr, ir = [], []
+    for j in range(b):
+        rows = np.flatnonzero(ok[j])
+        vj, ij = ref.topk_search_ref(q[j:j + 1], c[rows], k)
+        vr.append(np.asarray(vj[0]))
+        ir.append(rows[np.asarray(ij[0])])
+    vr, ir = np.stack(vr), np.stack(ir)
+    i = np.asarray(i)
+    assert ((i >= 0) & (i < n)).all()
+    assert np.take_along_axis(ok, i, axis=1).all()
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(np.sort(i, 1), np.sort(ir, 1))
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(np.asarray(v), np.asarray(vr),
-                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(v), vr, rtol=tol, atol=tol)
     # ids can differ on exact ties: check score-equivalence instead
     got = np.asarray(jnp.sum(c[i] * q[:, None, :], -1), np.float32)
-    np.testing.assert_allclose(got, np.asarray(vr, np.float32),
+    np.testing.assert_allclose(got, vr.astype(np.float32),
                                rtol=max(tol, 1e-4), atol=max(tol, 1e-4))
 
 
